@@ -214,9 +214,10 @@ def run_sharded(total_elems: int | None = None, n: int | None = None,
     trimmed_mean is omitted — it has no shard-local partial form and
     would rebuild the full stack per shard (see dist/registry.py)."""
     import jax
+    from repro.launch.mesh import make_mesh
 
     d = jax.device_count()
-    mesh = jax.make_mesh((d,), ("data",))
+    mesh = make_mesh((d,), ("data",))
     n = n or d
     if total_elems is None:
         total_elems = 2_000_000 if smoke else 768_000_000
@@ -261,6 +262,8 @@ def main(smoke: bool = False, out: str | None = "BENCH_agg.json",
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes, no JSON (CI stage 6)")

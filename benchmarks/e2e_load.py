@@ -375,6 +375,8 @@ def main(smoke: bool = False, do_record: bool = False, names=None,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help=f"truncate every scenario to {SMOKE_REQUESTS} "

@@ -152,4 +152,6 @@ def main(out_csv: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

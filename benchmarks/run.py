@@ -14,6 +14,7 @@ Prints ``name,us_per_call,derived`` CSV. Module map:
 from __future__ import annotations
 
 import argparse
+import sys
 import traceback
 
 
@@ -30,15 +31,19 @@ def main() -> None:
                          "with device count and mesh shape")
     args = ap.parse_args()
     want = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    failed = []
 
     def go(name, fn):
         if want and name not in want:
             return
         try:
             fn()
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # keep the harness going, fail at the end
             traceback.print_exc()
             print(f"{name},nan,ERROR:{type(e).__name__}", flush=True)
+            failed.append(name)
 
     print("name,us_per_call,derived")
 
@@ -81,6 +86,9 @@ def main() -> None:
     go("e2e", (lambda: e2e_load.main(smoke=True, do_record=args.record))
        if args.fast
        else (lambda: e2e_load.main(do_record=args.record)))
+    if failed:
+        print(f"failed phases: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -119,8 +119,8 @@ def run_engine(n_requests: int = 8, seed: int = 0, arch: str = "qwen2-0.5b",
         if jax.device_count() % tp:
             raise ValueError(f"tp={tp} does not divide "
                              f"{jax.device_count()} devices")
-        mesh = jax.make_mesh((jax.device_count() // tp, tp),
-                             ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((jax.device_count() // tp, tp), ("data", "model"))
     engine = ServeEngine(params, cfg, PagedCacheConfig(
         num_slots=2, page_size=8, num_pages=16, max_pages_per_seq=6),
         superstep_k=superstep_k, mesh=mesh)
@@ -349,6 +349,8 @@ def main(n_requests: int = 2000, engine_requests: int = 8,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sizes for CI")

@@ -19,11 +19,15 @@ reaches LeNet size. This module is the device twin:
                          the server's iteration is a single device
                          dispatch instead of a numpy pipeline.
 
-Donation contract: on accelerator backends the iterate (and the scatter's
-destination buffer) are donated, so updates are in place; callers must
-not hold references to ``GradLedger.data`` across an ``upload``. The CPU
-backend cannot donate (jax would only warn), so donation is disabled
-there — semantics are identical either way.
+Donation contract: the iterate (and the scatter's destination buffer)
+are donated on every backend, so updates are in place; callers must not
+hold references to ``GradLedger.data`` across an ``upload``.
+
+Width contract: a ledger row is ``ledger_width(P)`` wide — P rounded up
+to the aggregation kernels' column tile (``kernels.agg.TILE``), zero in
+the padding columns. The padding is allocated once, so the kernels never
+copy an ``(n, P)`` stack to pad it per call; every rule maps zero columns
+to zero, and the fused step slices its aggregate back to P.
 """
 from __future__ import annotations
 
@@ -34,10 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-PyTree = Any
+from repro.kernels.agg import TILE
 
-_DONATE: Tuple[int, ...] = (
-    () if jax.default_backend() == "cpu" else (0,))
+PyTree = Any
 
 
 class FlatLayout:
@@ -108,13 +111,22 @@ def ledger_dim(dim_or_layout_or_tree) -> int:
     return layout_of(dim_or_layout_or_tree).total
 
 
-def ledger_zeros(n_agents: int, dim_or_layout_or_tree) -> jnp.ndarray:
-    """The canonical flat ``(n, P)`` f32 ledger buffer. Every ledger in
-    the repo — :class:`GradLedger`, :class:`ShardedGradLedger`, and the
-    SPMD stale path's per-step buffer in ``launch/train.py`` — is built
-    through this one helper, so the layout contract exists once."""
-    return jnp.zeros((int(n_agents), ledger_dim(dim_or_layout_or_tree)),
-                     jnp.float32)
+def ledger_width(dim_or_layout_or_tree) -> int:
+    """Allocated row width: P rounded up to the kernels' column tile."""
+    p = ledger_dim(dim_or_layout_or_tree)
+    return -(-p // TILE) * TILE
+
+
+def ledger_zeros(n_agents: int, dim_or_layout_or_tree,
+                 sharding=None) -> jnp.ndarray:
+    """The canonical flat ``(n, ledger_width(P))`` f32 ledger buffer,
+    created in place on ``sharding``'s devices (the default device when
+    None). Every ledger in the repo — :class:`GradLedger`,
+    :class:`ShardedGradLedger`, and the SPMD stale path's per-step buffer
+    in ``launch/train.py`` — is built through this one helper, so the
+    layout contract exists once."""
+    return jnp.zeros((int(n_agents), ledger_width(dim_or_layout_or_tree)),
+                     jnp.float32, device=sharding)
 
 
 def layout_of(tree: PyTree, stacked: bool = False) -> FlatLayout:
@@ -136,25 +148,27 @@ def layout_of(tree: PyTree, stacked: bool = False) -> FlatLayout:
 # the persistent device ledger
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_rows(buf, idx, rows):
-    return buf.at[idx].set(rows)
+    """Write ``rows (k, P)`` into the first P columns of rows ``idx``."""
+    return buf.at[idx, :rows.shape[1]].set(rows)
 
 
 class GradLedger:
-    """Persistent ``(n_agents, P)`` f32 device buffer of per-agent
-    gradients. One instance lives for the whole server run; uploads are
-    in-place row scatters (the buffer is donated on accelerators), so the
+    """Persistent ``(n_agents, ledger_width(P))`` f32 device buffer of
+    per-agent gradients. One instance lives for the whole server run;
+    uploads are in-place row scatters (the buffer is donated), so the
     server never re-stacks or re-uploads the full ledger."""
 
-    def __init__(self, n_agents: int, dim_or_layout):
+    def __init__(self, n_agents: int, dim_or_layout, sharding=None):
         if isinstance(dim_or_layout, FlatLayout):
             self.layout: Optional[FlatLayout] = dim_or_layout
         else:
             self.layout = None
         self.n_agents = int(n_agents)
         self.dim = ledger_dim(dim_or_layout)
-        self.data = ledger_zeros(self.n_agents, self.dim)
+        self.sharding = sharding
+        self.data = ledger_zeros(self.n_agents, self.dim, sharding)
 
     def upload(self, idx, rows) -> None:
         """Scatter ``rows (k, P)`` into agent rows ``idx (k,)``."""
@@ -183,11 +197,18 @@ class GradLedger:
 
     # -- checkpointing ---------------------------------------------------
     def host(self) -> np.ndarray:
-        """Host f32 copy (snapshot form; restoring it is bit-exact)."""
-        return np.asarray(self.data)
+        """Host f32 ``(n, P)`` copy (snapshot form; restoring it is
+        bit-exact)."""
+        return np.asarray(self.data[:, :self.dim])
+
+    def _padded(self, arr) -> np.ndarray:
+        arr = np.asarray(arr, np.float32)
+        return np.pad(arr, ((0, 0), (0, ledger_width(self.dim) - self.dim)))
 
     def load(self, arr) -> None:
-        self.data = jnp.asarray(np.asarray(arr, np.float32))
+        # straight from host memory to the ledger's devices: a sharded
+        # ledger may be larger than any one device holds
+        self.data = jax.device_put(self._padded(arr), self.sharding)
 
 
 class ShardedGradLedger(GradLedger):
@@ -218,30 +239,31 @@ class ShardedGradLedger(GradLedger):
     """
 
     def __init__(self, n_agents: int, dim_or_layout, *, mesh, axes):
-        # bufs must exist before super().__init__ assigns self.data
-        # (the assignment routes through the property setter below)
-        self._bufs: list = [None, None]
-        self._cur = 0
-        super().__init__(n_agents, dim_or_layout)
         from jax.sharding import NamedSharding, PartitionSpec
         axes = (axes,) if isinstance(axes, str) else tuple(axes)
         n_shards = 1
         for a in axes:
             n_shards *= mesh.shape[a]
-        if self.n_agents % n_shards:
+        if int(n_agents) % n_shards:
             raise ValueError(
-                f"n_agents={self.n_agents} not divisible by the "
+                f"n_agents={int(n_agents)} not divisible by the "
                 f"{n_shards}-way dp sharding over axes {axes}")
         self.mesh = mesh
         self.axes = axes
         self.spec = PartitionSpec(axes if len(axes) > 1 else axes[0], None)
-        sharding = NamedSharding(mesh, self.spec)
-        zero = jax.device_put(self._bufs[self._cur], sharding)
+        # bufs must exist before super().__init__ assigns self.data
+        # (the assignment routes through the property setter below)
+        self._bufs: list = [None, None]
+        self._cur = 0
+        super().__init__(n_agents, dim_or_layout,
+                         sharding=NamedSharding(mesh, self.spec))
         # the two slots must be *independent* device buffers: _scatter_rows
-        # donates its destination on accelerator backends, so aliased slots
-        # would have the first upload invalidate the other buffer and the
-        # next pending replay would read a deleted array
-        self._bufs = [zero, jax.device_put(jnp.zeros_like(zero), sharding)]
+        # donates its destination, so aliased slots would have the first
+        # upload invalidate the other buffer and the next pending replay
+        # would read a deleted array. Both are created in place on their
+        # shards, never staged whole on one device.
+        self._bufs[1 - self._cur] = ledger_zeros(self.n_agents, self.dim,
+                                                 self.sharding)
         self._pending: list = []
         self.swaps = 0
 
@@ -277,13 +299,15 @@ class ShardedGradLedger(GradLedger):
     def load(self, arr) -> None:
         """Restore both buffers (a snapshot is a settled ledger — no
         pending uploads survive a restore)."""
-        from jax.sharding import NamedSharding
-        sharding = NamedSharding(self.mesh, self.spec)
-        host = jnp.asarray(np.asarray(arr, np.float32))
-        # two independent copies — never alias the slots (donation, above);
-        # jnp.copy forces a fresh buffer even where device_put would no-op
-        self._bufs = [jax.device_put(host, sharding),
-                      jax.device_put(jnp.copy(host), sharding)]
+        host = self._padded(arr)
+        # release the old pair first: at a model's width two pairs of
+        # ledger buffers do not fit beside each other
+        self._bufs = [None, None]
+        # two independent copies — never alias the slots (donation,
+        # above). The copy is made on the devices: a CPU put of one host
+        # array twice may hand back the same zero-copy buffer
+        first = jax.device_put(host, self.sharding)
+        self._bufs = [first, jnp.copy(first)]
         self._pending.clear()
 
 
@@ -297,9 +321,10 @@ def make_aggregate_apply(rule: str, f: int, gamma: float) -> Callable:
 
         x' = project_ball(x - eta * GradAgg(g, received), gamma)
 
-    Signature: ``(x (P,) f32, g (n, P) f32, received (n,) bool, eta)``.
-    The rule is the registry's ``bind_device`` twin (Pallas kernels on
-    TPU, jnp elsewhere); the iterate is donated on accelerators. The
+    Signature: ``(x (P,) f32, g (n, W) f32, received (n,) bool, eta)``
+    with ``W = ledger_width(P)``. The rule is the registry's
+    ``bind_device`` twin (Pallas kernels on TPU, jnp elsewhere); its
+    ``(W,)`` aggregate is sliced to P, and the iterate is donated. The
     host f64 reference path stays the conformance/golden bit stream —
     this is the opt-in ``EngineConfig.agg_backend="device"`` fast path.
 
@@ -312,10 +337,10 @@ def make_aggregate_apply(rule: str, f: int, gamma: float) -> Callable:
     dev = get_rule(rule).bind_device(f)
 
     def step(x, g, received, eta):
-        agg = dev(g, received).astype(jnp.float32)
+        agg = dev(g, received)[:x.shape[0]].astype(jnp.float32)
         return gradagg.project_ball(x - jnp.float32(eta) * agg, gamma)
 
-    return jax.jit(step, donate_argnums=_DONATE)
+    return jax.jit(step, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -333,7 +358,6 @@ def make_sharded_aggregate_apply(rule: str, f: int, gamma: float,
     """
     from jax.sharding import PartitionSpec as P
     from repro.core import gradagg
-    from repro.dist.compat import shard_map
     from repro.dist.registry import get_rule
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
     agg_loc = get_rule(rule).bind_sharded(f, axes=axes, n=n_agents,
@@ -341,14 +365,16 @@ def make_sharded_aggregate_apply(rule: str, f: int, gamma: float,
     row_spec = P(axes if len(axes) > 1 else axes[0], None)
 
     def body(x, g_loc, received, eta):
-        agg = agg_loc(g_loc, received).astype(jnp.float32)
+        agg = agg_loc(g_loc, received)[:x.shape[0]].astype(jnp.float32)
         return gradagg.project_ball(x - eta * agg, gamma)
 
-    smap = shard_map(body, mesh=mesh,
-                     in_specs=(P(), row_spec, P(), P()),
-                     out_specs=P(), axis_names=set(axes))
+    # manual over every mesh axis (the rest replicate): the rule's Pallas
+    # kernels cannot be partitioned automatically over an auto axis
+    smap = jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(), row_spec, P(), P()),
+                         out_specs=P(), check_vma=False)
 
     def step(x, g_loc, received, eta):
         return smap(x, g_loc, received, jnp.float32(eta))
 
-    return jax.jit(step, donate_argnums=_DONATE)
+    return jax.jit(step, donate_argnums=(0,))

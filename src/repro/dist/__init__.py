@@ -12,8 +12,6 @@ One coherent API over the paper's Algorithm 1 and its generalizations
   (``MeshRules``) plus tree/batch/cache PartitionSpec derivation.
 - ``repro.dist.act_sharding``  in-graph activation sharding constraints
   (``constrain`` / ``act_policy``) used by all model files.
-- ``repro.dist.compat``        version portability shims (shard_map /
-  set_mesh) for the pinned jax in this container.
 
 See DESIGN.md §1-§3 for the layer contract.
 """

@@ -64,7 +64,7 @@ def psum_all(x, axes):
 
 def _per_agent(x, axes):
     """Scatter this agent's scalar into an (n,) vector replicated on all
-    shards (one all-reduce; no all-gather — see compat notes)."""
+    shards (one all-reduce; no all-gather)."""
     n = axis_count(axes)
     onehot = (jnp.arange(n) == agent_index(axes))
     return psum_all(jnp.where(onehot, x, jnp.zeros_like(x)), axes)
@@ -153,8 +153,8 @@ def shard_row_slice(axes, n: int) -> Tuple[Any, int]:
 
 def ledger_all_rows(x_loc, axes, n: int):
     """Rebuild the full row-major ``(n, ...)`` array from this shard's
-    ``(n_loc, ...)`` row block (bit-exact; one psum, no all-gather —
-    see compat notes on the 0.4.37 all_gather partitioner)."""
+    ``(n_loc, ...)`` row block (bit-exact: one psum of a zero-padded
+    block, no all-gather)."""
     row0, n_loc = shard_row_slice(axes, n)
     if x_loc.shape[0] != n_loc:
         raise ValueError(

@@ -199,8 +199,8 @@ def _part_cge(g_loc, received, *, f, axes, n):
     # identical keep-set (the keep-set math exists once, same as cge_psum)
     norms = C.ledger_all_rows(row_norms(g_loc), axes, n)
     keep = gradagg.cge_mask_from_norms(norms, received, f)
-    keep_loc = _recv_local(keep, axes, n)
-    return C.psum_all(keep_loc.astype(jnp.float32) @ g_loc.astype(jnp.float32),
+    from repro.kernels.agg import masked_sum_dot
+    return C.psum_all(masked_sum_dot(g_loc, _recv_local(keep, axes, n)),
                       axes)
 
 

@@ -258,7 +258,7 @@ def cache_specs(rules: MeshRules, cache: PyTree,
 
     Paged pools (leaf names ``*_pages``, DESIGN.md §14): the page pool is
     a *global* resource indexed by the shared page table — never batch-
-    sharded. GQA ``k_pages``/``v_pages`` ``(n_periods, N, PS, n_kv, hd)``
+    sharded. GQA ``k_pages``/``v_pages`` ``(n_periods, N, n_kv, PS, hd)``
     shard the kv-head dim over tp (attention has no cross-kv-head
     reduction, so the tp split is exact and the grouped decode kernel's
     ``(B, Hkv, Pmax)`` grid splits per shard); MLA latent pools
@@ -281,12 +281,12 @@ def cache_specs(rules: MeshRules, cache: PyTree,
         shape = tuple(leaf.shape)
         dims = [None] * len(shape)
         if name.endswith("_pages"):
-            if name in ("k_pages", "v_pages") and len(shape) >= 2:
+            if name in ("k_pages", "v_pages") and len(shape) >= 3:
                 ts = rules.size(rules.tp_axes)
-                if (rules.tp_axes and shape[-2] % ts == 0
+                if (rules.tp_axes and shape[-3] % ts == 0
                         and (n_query_heads is None
                              or n_query_heads % ts == 0)):
-                    dims[-2] = (rules.tp_axes[0]
+                    dims[-3] = (rules.tp_axes[0]
                                 if len(rules.tp_axes) == 1
                                 else rules.tp_axes)
             specs.append(P(*dims))

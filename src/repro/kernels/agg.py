@@ -25,19 +25,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 BIG = 1e30          # matches gradagg.BIG (received-masking sentinel)
+TILE = 2048         # column tile; ledgers allocate P rounded up to it
 
 
 def _pad_cols(x, tile: int):
     """Zero-pad the last axis to a tile multiple (padding columns are
     harmless for every rule: zero squared-norm contribution, and callers
-    slice the output back to P)."""
+    slice the output back to P). A ledger is allocated at a tile multiple
+    (``core.ledger.ledger_width``), so on the server path this is free."""
     pad = (-x.shape[-1]) % tile
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
@@ -45,7 +43,7 @@ def _pad_cols(x, tile: int):
 
 
 def _seq_params(interpret: bool, ndims: int):
-    if pltpu is not None and not interpret:
+    if not interpret:
         return {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * ndims)}
     return {}
@@ -55,7 +53,7 @@ def _seq_params(interpret: bool, ndims: int):
 # CGE: norms + keep-set + masked sum, one pass structure
 
 
-def masked_cge_reduce(g, received, f: int, *, tile: int = 2048,
+def masked_cge_reduce(g, received, f: int, *, tile: int = TILE,
                       interpret: bool = False):
     """g: (n, P) f32, received: (n,) bool -> (P,) f32 — sum of the m-f
     smallest-norm received gradients (CGE filter, paper eq. (18)).
@@ -151,7 +149,7 @@ def _running_cut(lo, hi, f: int):
     return cut
 
 
-def trimmed_mean_tiled(g, received, f: int, *, tile: int = 2048,
+def trimmed_mean_tiled(g, received, f: int, *, tile: int = TILE,
                        interpret: bool = False):
     """g: (n, P) f32, received: (n,) bool -> (P,) f32 — per coordinate,
     drop the f largest and f smallest received values, average the rest
@@ -200,8 +198,10 @@ def masked_sum_dot(g, received):
     row reduction is severalfold faster than mask-multiply + reduce on
     every backend and is the production form of the sum/mean device
     twins (same math as ``gradagg.agg_sum``; accumulation order differs,
-    so the f64 host reference stays the conformance bit stream)."""
-    return received.astype(jnp.float32) @ g.astype(jnp.float32)
+    so the f64 host reference stays the conformance bit stream). HIGHEST
+    precision: a TPU's default f32 matmul rounds its inputs to bf16."""
+    return jnp.dot(received.astype(jnp.float32), g.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
 
 
 def row_norms(g):
@@ -219,7 +219,7 @@ def masked_cge_dot(g, received, f: int):
     — the non-TPU twin of :func:`masked_cge_reduce`."""
     from repro.core.gradagg import cge_mask_from_norms  # shared keep-set
     keep = cge_mask_from_norms(row_norms(g), received, f)
-    return keep.astype(jnp.float32) @ g.astype(jnp.float32)
+    return masked_sum_dot(g, keep)
 
 
 def trimmed_mean_running(g, received, f: int):
@@ -244,7 +244,7 @@ def trimmed_mean_running(g, received, f: int):
 # int8 dequantize + masked accumulate
 
 
-def dequant_accum(q, scale, received, *, tile: int = 2048,
+def dequant_accum(q, scale, received, *, tile: int = TILE,
                   interpret: bool = False):
     """q: (n, P) int8, scale: (n,) f32, received: (n,) bool -> (P,) f32.
 

@@ -10,18 +10,10 @@ Validated in interpret mode against ``ref.py``.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _norm_kernel(x_ref, o_ref):
@@ -51,7 +43,7 @@ def block_sq_norms(x, *, block: int = 2048, interpret: bool = False):
         o_ref[0, 0] = o_ref[0, 0] + jnp.sum(xb * xb)
 
     kwargs = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     out = pl.pallas_call(
@@ -82,7 +74,7 @@ def masked_scale(x, scale, *, block: int = 2048, interpret: bool = False):
                       * s_ref[0, 0]).astype(o_ref.dtype)
 
     kwargs = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(

@@ -2,7 +2,12 @@
 
 The serving path stores KV in fixed-size physical pages
 (``repro.serve.kv_cache``); at decode each sequence holds a page table
-mapping logical pages to physical ones. The kernel grids over
+mapping logical pages to physical ones. Pools are head-major,
+``(N, Hkv, PS, D)``, so one KV head of one page is a ``(PS, D)`` tile
+whose last two dims are whole array dims — the only block shape the
+TPU lowering accepts when Hkv is below the 8-row sublane tile (a
+sequence-major ``(N, PS, Hkv, D)`` pool would need a ``(PS, 1, D)``
+block with a second-minor extent of 1 against Hkv). The kernel grids over
 **(B, Hkv, Pmax)** with a ``(G, D)`` query block per KV head
 (``G = H // Hkv``): all query heads that share a KV head score against
 one fetched page, so each page is moved HBM->VMEM **once per KV head**
@@ -25,9 +30,8 @@ at position kv_len-1, so the length mask is exactly the causal mask
 (cross-attention decode passes the memory length instead — same mask).
 
 TPU is the target; correctness is validated on CPU via ``interpret=True``
-against ``ref.ref_paged_decode_attention`` (tests/test_kernels_decode.py).
-When the TPU helpers are unavailable (CPU-only installs) the public entry
-falls back to the oracle — same contract as ``kernels/ops.py``.
+against ``ref.ref_paged_decode_attention`` (tests/test_kernels_decode.py),
+and ``tests/test_tpu_compile.py`` compiles it for a v5e chip.
 """
 from __future__ import annotations
 
@@ -36,13 +40,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only helpers; the jnp fallback works without them
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -51,7 +49,7 @@ def tp_paged_decode(q, k_pages, v_pages, page_table, kv_lens, *,
                     mesh, tp_axes=("model",), impl: str = "auto"):
     """Tensor-parallel grouped paged decode (DESIGN.md §14).
 
-    q: (B, H, D); k_pages/v_pages: (N, PS, Hkv, D|Dv) sharded over the
+    q: (B, H, D); k_pages/v_pages: (N, Hkv, PS, D|Dv) sharded over the
     kv-head dim per ``dist.sharding.cache_specs``; page_table/kv_lens
     replicated. Invokes the grouped decode kernel per shard through
     shard_map — each shard runs the full ``(B, Hkv/tp, Pmax)`` grid on
@@ -70,7 +68,7 @@ def tp_paged_decode(q, k_pages, v_pages, page_table, kv_lens, *,
     from repro.kernels import ops as K     # lazy: ops imports this module
 
     b, h, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     tp_axes = tuple(tp_axes)
     ts = 1
     for a in tp_axes:
@@ -78,17 +76,19 @@ def tp_paged_decode(q, k_pages, v_pages, page_table, kv_lens, *,
     if ts == 1 or h % ts or hkv % ts:
         return K.paged_decode_attention(q, k_pages, v_pages, page_table,
                                         kv_lens, impl=impl)
-    from repro.dist.compat import shard_map
     tp = tp_axes[0] if len(tp_axes) == 1 else tp_axes
 
     def body(q_, kp_, vp_, tbl_, l_):
         return K.paged_decode_attention(q_, kp_, vp_, tbl_, l_, impl=impl)
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P(None, tp, None), P(None, None, tp, None),
-                            P(None, None, tp, None), P(None, None),
-                            P(None)),
-                  out_specs=P(None, tp, None), axis_names=set(tp_axes))
+    # manual over every mesh axis (axes the specs omit are replicated): a
+    # Mosaic kernel cannot be partitioned automatically over a leftover
+    # auto axis, even one of size 1
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(None, tp, None), P(None, tp, None, None),
+                                P(None, tp, None, None), P(None, None),
+                                P(None)),
+                      out_specs=P(None, tp, None), check_vma=False)
     out = f(q, k_pages, v_pages, page_table, kv_lens)
     # exact gather boundary: concatenating the per-shard head blocks is
     # bit-exact, and the replicated wo matmul that follows then matches
@@ -121,8 +121,8 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p < _pages_used(ln, page_size))
     def _accum():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (PS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)         # (PS, Dv)
+        k = k_ref[0, 0].astype(jnp.float32)            # (PS, D)
+        v = v_ref[0, 0].astype(jnp.float32)            # (PS, Dv)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -153,7 +153,7 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, kv_lens, *,
                        interpret: bool = False):
-    """q: (B,H,D); k_pages: (N,PS,Hkv,D); v_pages: (N,PS,Hkv,Dv);
+    """q: (B,H,D); k_pages: (N,Hkv,PS,D); v_pages: (N,Hkv,PS,Dv);
     page_table: (B,Pmax) int32; kv_lens: (B,) int32. Returns (B,H,Dv).
 
     KV heads are grouped: head h reads KV head h // (H // Hkv), i.e. the
@@ -164,16 +164,11 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, kv_lens, *,
     page, and the walk early-exits after ceil(kv_len / PS) pages anyway.
     """
     b, h, d = q.shape
-    n, ps, hkv, dv = v_pages.shape
+    n, hkv, ps, dv = v_pages.shape
     assert h % hkv == 0, (h, hkv)
     g = h // hkv
     pmax = page_table.shape[1]
     scale = d ** -0.5
-
-    if pltpu is None:  # pragma: no cover - CPU-only installs
-        from repro.kernels.ref import ref_paged_decode_attention
-        return ref_paged_decode_attention(q, k_pages, v_pages, page_table,
-                                          kv_lens)
 
     tbl = jnp.maximum(page_table, 0).astype(jnp.int32)
     lens = kv_lens.astype(jnp.int32)
@@ -185,7 +180,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, kv_lens, *,
         # clamp the walk to the pages actually resident: for p >= used the
         # block index equals the previous step's, so the copy is elided
         p_eff = jnp.minimum(p_, _pages_used(l_[b_], ps) - 1)
-        return (tbl_[b_, p_eff], 0, h_, 0)
+        return (tbl_[b_, p_eff], h_, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -193,15 +188,15 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, kv_lens, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda b_, h_, p_, tbl_, l_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, dv), kv_map),
+            pl.BlockSpec((1, 1, ps, d), kv_map),
+            pl.BlockSpec((1, 1, ps, dv), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
                                lambda b_, h_, p_, tbl_, l_: (b_, h_, 0, 0)),
         scratch_shapes=[
-            _VMEM((g, 1), jnp.float32),
-            _VMEM((g, 1), jnp.float32),
-            _VMEM((g, dv), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, dv), jnp.float32),
         ],
     )
 

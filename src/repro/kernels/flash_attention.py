@@ -15,13 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only helpers; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -93,22 +87,14 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     ]
     out_spec = pl.BlockSpec((1, 1, block_q, dv),
                             lambda b_, h_, iq, ik: (b_, h_, iq, 0))
-    # without the TPU helpers (CPU-only installs) scratch still has to match
-    # the kernel signature (m_ref, l_ref, acc_ref); route it through the
-    # backend-agnostic ANY memory space and force interpret mode, since
-    # nothing can compile a TPU kernel there anyway
-    mem = _VMEM if _VMEM is not None else (
-        lambda shape, dt: pl.MemoryRef(shape, dt, pl.ANY))
-    if _VMEM is None:
-        interpret = True
     scratch_shapes = [
-        mem((block_q,), jnp.float32),
-        mem((block_q,), jnp.float32),
-        mem((block_q, dv), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q, dv), jnp.float32),
     ]
 
     kwargs = {}
-    if pltpu is not None and not interpret:
+    if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))

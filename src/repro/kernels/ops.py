@@ -1,9 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On TPU these call the kernels; elsewhere (this CPU container) they fall
-back to ``interpret=True`` (tests) or the jnp reference (production CPU
-path — the dry-run/roofline path never routes through Pallas, see
-DESIGN.md §6).
+``impl="auto"`` runs the compiled kernel when the default backend is a
+TPU and the portable jnp form on any other backend (the CPU test path;
+the dry-run/roofline path never routes through Pallas, see DESIGN.md §6).
+``impl="interpret"`` runs the kernel in Pallas interpret mode and
+``impl="ref"`` the oracle, on any backend. Nothing falls back silently:
+on a TPU, "auto" always compiles the kernel.
 """
 from __future__ import annotations
 
@@ -18,10 +20,7 @@ from repro.kernels import ref as _ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "impl"))
@@ -37,7 +36,7 @@ def flash_attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
                            impl: str = "auto"):
     """Single-query attention over paged KV (serving decode hot path).
-    q: (B,H,D); k_pages/v_pages: (N,PS,Hkv,D/Dv); page_table: (B,Pmax);
+    q: (B,H,D); k_pages/v_pages: (N,Hkv,PS,D/Dv); page_table: (B,Pmax);
     kv_lens: (B,). Returns (B,H,Dv).
 
     Both implementations are KV-head grouped (head h reads KV head

@@ -25,8 +25,9 @@ def ref_paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens):
     """Single-query attention over a paged KV cache (pure-jnp oracle).
 
     q: (B, H, D) — one query token per sequence.
-    k_pages: (N, PS, Hkv, D); v_pages: (N, PS, Hkv, Dv) — the physical page
-        pool (N pages of PS tokens each), KV heads grouped (H % Hkv == 0).
+    k_pages: (N, Hkv, PS, D); v_pages: (N, Hkv, PS, Dv) — the head-major
+        physical page pool (N pages of PS tokens each), KV heads grouped
+        (H % Hkv == 0).
     page_table: (B, Pmax) int32 — logical page p of sequence b lives in
         physical page page_table[b, p]; entries past the sequence may be
         any *valid* index (they are masked by kv_lens).
@@ -37,7 +38,7 @@ def ref_paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens):
     Returns (B, H, Dv) in q.dtype with an fp32 softmax.
 
     Grouped math, mirroring the kernel: the query is reshaped to
-    (B, Hkv, G, D) and contracted against the *un-repeated* (B, T, Hkv, ·)
+    (B, Hkv, G, D) and contracted against the *un-repeated* (B, Hkv, T, ·)
     gathered KV — head h of the flat output is group lane h % G of KV head
     h // G, the layout ``jnp.repeat(kv, G, axis=heads)`` expands to. This
     is also the production CPU path (``kernels/ops`` routes non-TPU "auto"
@@ -45,25 +46,27 @@ def ref_paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens):
     aesthetics.
     """
     b, h, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv, ps = k_pages.shape[1], k_pages.shape[2]
     g = h // hkv
-    ps = k_pages.shape[1]
     dv = v_pages.shape[-1]
     tbl = jnp.maximum(page_table, 0)
-    k = k_pages[tbl]                       # (B, Pmax, PS, Hkv, D)
-    v = v_pages[tbl]
-    t = k.shape[1] * ps
-    k = k.reshape(b, t, hkv, -1)
-    v = v.reshape(b, t, hkv, -1)
+    pmax = tbl.shape[1]
+    t = pmax * ps
+
+    def gather(pool):                      # (B, Pmax, Hkv, PS, .) -> (B, Hkv, T, .)
+        x = pool[tbl].transpose(0, 2, 1, 3, 4)
+        return x.reshape(b, hkv, t, -1).astype(jnp.float32)
+
+    k, v = gather(k_pages), gather(v_pages)
     qg = q.reshape(b, hkv, g, d)
-    s_ = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
-                    k.astype(jnp.float32)) * (d ** -0.5)
+    s_ = jnp.einsum("bkgd,bktd->bkgt", qg.astype(jnp.float32), k) \
+        * (d ** -0.5)
     mask = jnp.arange(t)[None, :] < kv_lens[:, None]          # (B, T)
     s_ = jnp.where(mask[:, None, None, :], s_, NEG_INF)
     w = jax.nn.softmax(s_, axis=-1)
     # all-masked rows (kv_len == 0) produce a uniform softmax; zero them
     w = jnp.where(jnp.any(mask, axis=1)[:, None, None, None], w, 0.0)
-    out = jnp.einsum("bkgt,btkv->bkgv", w, v.astype(jnp.float32))
+    out = jnp.einsum("bkgt,bktv->bkgv", w, v)
     return out.reshape(b, h, dv).astype(q.dtype)
 
 

@@ -24,7 +24,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs.base import SHAPES, get_shape           # noqa: E402
 from repro.configs.registry import get_config, list_configs  # noqa: E402
-from repro.dist.compat import set_mesh                     # noqa: E402
 from repro.dist.sharding import (MeshRules, tree_specs, batch_specs,
                                  cache_specs)               # noqa: E402
 from repro.launch.mesh import make_production_mesh, n_agents_of  # noqa: E402
@@ -186,7 +185,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
                      in_shardings=(_mk_shardings(mesh, st_specs),
                                    _mk_shardings(mesh, bt_specs),
                                    NamedSharding(mesh, P())))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jf.lower(state, batch, fresh)
     elif kind == "train":
         state = T.abstract_state(cfg, tc, max_pos=max_pos_for(shape),
@@ -202,7 +201,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
                      in_shardings=(_mk_shardings(mesh, st_specs),
                                    _mk_shardings(mesh, bt_specs)),
                      donate_argnums=(0,))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jf.lower(state, batch)
     elif kind == "prefill":
         state = state_specs(cfg, shape, optimizer="none")
@@ -213,7 +212,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         step = V.make_prefill_step(cfg, moe_groups=n_ag, dp=dp, tp=tp, sizes=sizes)
         jf = jax.jit(step, in_shardings=(_mk_shardings(mesh, p_specs),
                                          _mk_shardings(mesh, bt_specs)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jf.lower(params, batch)
     else:  # decode
         state = state_specs(cfg, shape, optimizer="none")
@@ -228,7 +227,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         jf = jax.jit(step, in_shardings=(_mk_shardings(mesh, p_specs),
                                          _mk_shardings(mesh, b_specs)),
                      donate_argnums=(1,))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jf.lower(params, batch)
     return lowered, meta
 

@@ -76,7 +76,10 @@ class TrainLoop:
         self.data_iter = data_iter
         self.n_agents = n_agents
         self.oracle = oracle or StragglerOracle(n_agents, r, seed=seed)
-        self.step_fn = jax.jit(make_train_step(cfg, tc, moe_groups=n_agents))
+        # the state is donated: old and new params + optimizer moments
+        # would not both fit a 16 GB chip at a 0.5B model's full width
+        self.step_fn = jax.jit(make_train_step(cfg, tc, moe_groups=n_agents),
+                               donate_argnums=(0,))
         self.state = init_state(jax.random.PRNGKey(seed), cfg, tc,
                                 max_pos=max_pos, n_agents=n_agents)
         self.ckpt = Checkpointer(ckpt_dir) if ckpt_dir else None

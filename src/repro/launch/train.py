@@ -34,7 +34,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.ledger import layout_of, ledger_zeros
 from repro.dist import collectives as C
-from repro.dist.compat import shard_map
 from repro.dist.registry import resolve_mode
 from repro.dist.sharding import MeshRules, tree_specs, batch_specs
 from repro.launch.mesh import dp_axis_names, n_agents_of
@@ -241,12 +240,14 @@ def make_general_step(cfg: ArchConfig, tc: TrainConfig, mesh,
             loss = _psum_all(loss * mask_self, dp)
         elif tc.mode == "stale":
             layout = layout_of(grads)   # cached shared layout (module top)
-            ledger_self = state["ledger"]["g"][0]          # (P,) flat
+            ledger_self = state["ledger"]["g"][0]          # (W,) flat
             ts_self = state["ledger"]["ts"][0]
             fresh = mask_self > 0
             new_ts = jnp.where(fresh, state["step"], ts_self)
             usable = (state["step"] - new_ts) <= tc.tau
-            contrib = jnp.where(fresh, layout.flatten(grads), ledger_self)
+            flat = layout.flatten(grads)                    # (P,), P <= W
+            flat = jnp.pad(flat, (0, ledger_self.shape[0] - flat.shape[0]))
+            contrib = jnp.where(fresh, flat, ledger_self)
             agg_flat = rule.collective(contrib,
                                        usable.astype(jnp.float32), dp)
             agg = layout.unflatten(agg_flat, dtype=jnp.float32)
@@ -306,7 +307,7 @@ def make_general_step(cfg: ArchConfig, tc: TrainConfig, mesh,
     def step(state, batch, fresh_mask):
         st_specs, bt_specs, fm_spec = in_specs_of(state, batch, fresh_mask)
         out_state_specs = jax.tree.map(lambda s: s, st_specs)
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(local),
             mesh=mesh,
             in_specs=(st_specs, bt_specs, fm_spec),

@@ -131,11 +131,14 @@ def _proj_qkv(p, x, kv_x, cfg: ArchConfig):
     return q, k, v
 
 
-def _paged_append(pool, new, page_table, lens, ps):
-    """Write one token per sequence into its page pool. pool:
-    (N, PS, ...); new: (B, ...); position = lens[b] in logical pages."""
+def _paged_append(pool, new, page_table, lens, ps, *, head_major=False):
+    """Write one token per sequence into its page pool; position = lens[b]
+    in logical pages. pool: (N, PS, ...) with new (B, ...), or the
+    head-major GQA pool (N, Hkv, PS, hd) with new (B, Hkv, hd)."""
     b = new.shape[0]
     phys = page_table[jnp.arange(b), lens // ps]     # (B,)
+    if head_major:
+        return pool.at[phys, :, lens % ps].set(new.astype(pool.dtype))
     return pool.at[phys, lens % ps].set(new.astype(pool.dtype))
 
 
@@ -205,11 +208,11 @@ def apply_gqa(p, x, cfg: ArchConfig, *, positions=None, kv_x=None,
             # it on device across K tokens without a host round-trip.
             from repro.kernels.ops import paged_decode_attention
             lens = cache_index
-            ps = cache["k_pages"].shape[1]
+            ps = cache["k_pages"].shape[2]            # (N, Hkv, PS, hd)
             kp = _paged_append(cache["k_pages"], k_new[:, 0], page_table,
-                               lens, ps)
+                               lens, ps, head_major=True)
             vp = _paged_append(cache["v_pages"], v_new[:, 0], page_table,
-                               lens, ps)
+                               lens, ps, head_major=True)
             tp_ctx = current_serve_tp()
             if tp_ctx is not None:
                 # serving TP (DESIGN.md §14): kv-head-sharded pools, the
@@ -320,13 +323,14 @@ def _mla_tp_shard(absorbed, q_nope, q_rope, w_uk, w_uv, ckv, kr, kv_len,
     if ts == 1 or h % ts:
         return absorbed(q_nope, q_rope, w_uk, w_uv, ckv, kr, kv_len)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.dist.compat import shard_map
     tp = tp_axes[0] if len(tp_axes) == 1 else tp_axes
-    f = shard_map(absorbed, mesh=mesh,
-                  in_specs=(P(None, None, tp, None), P(None, None, tp, None),
-                            P(None, tp, None), P(None, tp, None),
-                            P(), P(), P()),
-                  out_specs=P(None, None, tp, None), axis_names=set(tp_axes))
+    f = jax.shard_map(absorbed, mesh=mesh,
+                      in_specs=(P(None, None, tp, None),
+                                P(None, None, tp, None),
+                                P(None, tp, None), P(None, tp, None),
+                                P(), P(), P()),
+                      out_specs=P(None, None, tp, None),
+                      axis_names=set(tp_axes), check_vma=False)
     out = f(q_nope, q_rope, w_uk, w_uv, ckv, kr, kv_len)
     return jax.lax.with_sharding_constraint(out, NamedSharding(mesh, P()))
 

@@ -208,13 +208,11 @@ class ServeEngine:
 
         self._prefill = jax.jit(_prefill)
         # donate the cache so the single-token page append updates the
-        # pools in place instead of copying every pool every step (the
-        # CPU backend can't donate and would only warn, so skip there)
-        donate = () if jax.default_backend() == "cpu" else (2,)
-        self._decode = jax.jit(_decode, donate_argnums=donate)
+        # pools in place instead of copying every pool every step
+        self._decode = jax.jit(_decode, donate_argnums=(2,))
         # one compiled program per distinct K (bounded by superstep_k)
         self._superstep = jax.jit(_superstep, static_argnames=("k",),
-                                  donate_argnums=donate)
+                                  donate_argnums=(2,))
         # prompts admit in groups of one padded length each; padding to a
         # page multiple bounds the jit shape set to max_pages_per_seq
         # buckets. Right-padding is invisible to *causal attention*

@@ -11,7 +11,9 @@ row per serving slot, overwritten on admission.
 Layout per pattern position (mirrors ``models.model.init_cache``; the
 leading axis is ``n_periods`` so the stack scans):
 
-- GQA:  ``{"k_pages", "v_pages"}: (P, N, PS, n_kv, hd)``
+- GQA:  ``{"k_pages", "v_pages"}: (P, N, n_kv, PS, hd)`` — head-major,
+  so one KV head of one page is a contiguous ``(PS, hd)`` tile, the
+  block the Pallas decode kernel reads (``kernels/decode_attention.py``)
 - MLA:  ``{"ckv_pages": (P, N, PS, rank), "kr_pages": (P, N, PS, rope)}``
 - mamba/rwkv: dense slot states, exactly ``init_cache`` with
   ``batch=num_slots``.
@@ -38,6 +40,13 @@ from repro.configs.base import ArchConfig
 from repro.models.model import init_cache
 
 PAGED_SUFFIX = "_pages"
+HEAD_MAJOR = ("k_pages", "v_pages")     # (P, N, n_kv, PS, hd) pools
+
+
+def _swap_page_rows(name: str, x):
+    """Page rows ``(P, n, PS, ...)`` token-major <-> the pool's layout
+    (for a head-major pool the transpose is its own inverse)."""
+    return x.transpose(0, 1, 3, 2, 4) if name in HEAD_MAJOR else x
 
 
 @dataclasses.dataclass(frozen=True)
@@ -173,8 +182,8 @@ def _paged_block(cfg: ArchConfig, ccfg: PagedCacheConfig, dt):
         return {"ckv_pages": z((P, N, PS, m.kv_lora_rank)),
                 "kr_pages": z((P, N, PS, m.qk_rope_head_dim))}
     hd = cfg.resolved_head_dim
-    return {"k_pages": z((P, N, PS, cfg.n_kv_heads, hd)),
-            "v_pages": z((P, N, PS, cfg.n_kv_heads, hd))}
+    return {"k_pages": z((P, N, cfg.n_kv_heads, PS, hd)),
+            "v_pages": z((P, N, cfg.n_kv_heads, PS, hd))}
 
 
 class PagedKVCache:
@@ -348,12 +357,16 @@ class PagedKVCache:
                         chunk = chunk.reshape(
                             chunk.shape[0], n_full, ps, *chunk.shape[2:])
                         pool = pool.at[:, full_idx].set(
-                            chunk.astype(pool.dtype))
+                            _swap_page_rows(name, chunk).astype(pool.dtype))
                     if prompt_len % ps:
                         tail = dense[:, 0, n_full * ps: prompt_len]
-                        pool = pool.at[:, pages[n_full],
-                                       : prompt_len % ps].set(
-                            tail.astype(pool.dtype))
+                        r = prompt_len % ps
+                        if name in HEAD_MAJOR:
+                            pool = pool.at[:, pages[n_full], :, :r].set(
+                                tail.transpose(0, 2, 1, 3).astype(pool.dtype))
+                        else:
+                            pool = pool.at[:, pages[n_full], :r].set(
+                                tail.astype(pool.dtype))
                     mix[name] = pool
                 blk["mixer"] = mix
             else:
@@ -605,6 +618,7 @@ class PagedKVCache:
         ln = int(self.kv_lens[slot])
         pool = self.cache[pos]["mixer"][name]
         tbl = self.page_table[slot]
-        out = pool[:, tbl[: pages_needed(max(ln, 1), ps)]]
-        out = out.reshape(pool.shape[0], -1, *pool.shape[3:])
+        out = _swap_page_rows(
+            name, pool[:, tbl[: pages_needed(max(ln, 1), ps)]])
+        out = out.reshape(out.shape[0], -1, *out.shape[3:])
         return out[:, :ln]

@@ -1,12 +1,7 @@
-"""Test bootstrap: register the in-tree hypothesis stub when the real
-package is absent (the container bakes no hypothesis and installing is
-not allowed — see tests/helpers/hypothesis_stub.py), and gate the
-``wallclock`` marker (real-timer tests are only trustworthy on a box
-that isn't thrashing — scripts/ci.sh stage 12 opts in via
-``RUN_WALLCLOCK=1`` under a hard timeout)."""
-import importlib.util
+"""Test bootstrap: gate the ``wallclock`` marker (real-timer tests are
+only trustworthy on a box that isn't thrashing — scripts/ci.sh stage 12
+opts in via ``RUN_WALLCLOCK=1`` under a hard timeout)."""
 import os
-import sys
 
 import pytest
 
@@ -19,19 +14,3 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "wallclock" in item.keywords:
             item.add_marker(skip)
-
-
-def _install_hypothesis_stub() -> None:
-    path = os.path.join(os.path.dirname(__file__), "helpers",
-                        "hypothesis_stub.py")
-    spec = importlib.util.spec_from_file_location("hypothesis", path)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules["hypothesis"] = mod
-    spec.loader.exec_module(mod)
-    sys.modules["hypothesis.strategies"] = mod.strategies
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_stub()

@@ -6,6 +6,9 @@ Exits 0 iff all checks pass; prints one line per check.
 """
 import os
 
+# virtual CPU devices: pinned here, not inherited, so a run on a machine
+# with an accelerator never reaches for it
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import numpy as np            # noqa: E402
@@ -15,7 +18,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.core import gradagg                 # noqa: E402
 from repro.dist import collectives as C        # noqa: E402
-from repro.dist.compat import set_mesh, shard_map  # noqa: E402
 from repro.launch.mesh import make_test_mesh   # noqa: E402
 
 
@@ -38,8 +40,8 @@ def main():
         me = C.agent_index(("data",))
         return C.masked_psum({"g": gl[0]}, m[me], ("data",))["g"]
 
-    with set_mesh(mesh):
-        out = jax.jit(shard_map(
+    with jax.set_mesh(mesh):
+        out = jax.jit(jax.shard_map(
             f, in_specs=(P("data"), P()), out_specs=P(),
             axis_names={"data"}, check_vma=False))(g_all, mask)
     ref = gradagg.agg_sum(g_all, mask > 0)
@@ -53,8 +55,8 @@ def main():
         agg, keep = C.cge_psum({"g": gl[0]}, m[me] > 0, f_byz, ("data",))
         return agg["g"], keep
 
-    with set_mesh(mesh):
-        out, keep = jax.jit(shard_map(
+    with jax.set_mesh(mesh):
+        out, keep = jax.jit(jax.shard_map(
             fc, in_specs=(P("data"), P()), out_specs=(P(), P()),
             axis_names={"data"}, check_vma=False))(g_all, mask)
     ref = gradagg.agg_cge(g_all, mask > 0, f_byz)
@@ -71,8 +73,8 @@ def main():
         return agg["g"], err["g"][None]
 
     err0 = jnp.zeros((n, dim))
-    with set_mesh(mesh):
-        out, err = jax.jit(shard_map(
+    with jax.set_mesh(mesh):
+        out, err = jax.jit(jax.shard_map(
             fq, in_specs=(P("data"), P(), P("data")),
             out_specs=(P(), P("data")),
             axis_names={"data"}, check_vma=False))(g_all, mask, err0)
@@ -98,7 +100,7 @@ def main():
                  "weights": jnp.ones(tok.shape, jnp.float32)}
         fresh = jnp.asarray([1.0, 1.0, 0.0, 1.0])
         step = make_general_step(cfg, tc, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             new_state, metrics = jax.jit(step)(state, batch, fresh)
         ok = bool(jnp.isfinite(metrics["loss"])) and \
             int(new_state["step"]) == 1
@@ -123,7 +125,7 @@ def main():
                            sizes={"data": 4, "model": 2})
     mk = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                 is_leaf=lambda x: isinstance(x, P))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jf = jax.jit(step, in_shardings=(mk(st_specs), mk(bt_specs)))
         new_state, metrics = jf(state, batch)
     check(f"masked_pjit loss={float(metrics['loss']):.3f}",
@@ -132,7 +134,7 @@ def main():
     # --- masked == subset-gradient equivalence under pjit --------------
     w0 = jnp.ones(tok.shape, jnp.float32).at[:4].set(0.0)
     batch0 = dict(batch, weights=w0)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s1, m1 = jf(state, batch0)
     # reference: unsharded masked step
     step_ref = make_train_step(cfg, tc)

@@ -23,6 +23,9 @@ be set before jax initializes):
 """
 import os
 
+# virtual CPU devices: pinned here, not inherited, so a run on a machine
+# with an accelerator never reaches for it
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np            # noqa: E402
@@ -31,7 +34,6 @@ import jax.numpy as jnp       # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.dist import collectives as C          # noqa: E402
-from repro.dist.compat import shard_map          # noqa: E402
 from repro.dist.registry import get_rule, rule_names  # noqa: E402
 from repro.launch.mesh import make_test_mesh     # noqa: E402
 
@@ -52,7 +54,7 @@ def spmd_apply(mesh, dp_axes, rule, g_all, mask, f):
         me = C.agent_index(dp_axes)
         return rule.spmd({"g": gl[0]}, m[me], f, dp_axes)["g"]
 
-    fn = jax.jit(shard_map(body, mesh=mesh,
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
                            in_specs=(P(dp_spec), P()), out_specs=P(),
                            axis_names=set(dp_axes), check_vma=False))
     return np.asarray(fn(g_all, mask))

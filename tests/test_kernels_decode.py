@@ -1,7 +1,6 @@
 """Paged flash-decode kernel vs the pure-jnp oracle (interpret=True on
 CPU): GQA grouping, ragged last page, empty slots, causal self-decode and
-cross-attention-length masking, plus the flash_attention pltpu-free
-fallback regression."""
+cross-attention-length masking. Pools are head-major (N, Hkv, PS, D)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,8 +21,8 @@ SHAPES = [
 
 
 def _pool(rng, num_pages, ps, hkv, d, dv, dtype):
-    k = jnp.asarray(rng.normal(size=(num_pages, ps, hkv, d)), dtype)
-    v = jnp.asarray(rng.normal(size=(num_pages, ps, hkv, dv)), dtype)
+    k = jnp.asarray(rng.normal(size=(num_pages, hkv, ps, d)), dtype)
+    v = jnp.asarray(rng.normal(size=(num_pages, hkv, ps, dv)), dtype)
     return k, v
 
 
@@ -70,8 +69,8 @@ def test_decode_matches_contiguous_attention():
     lens = jnp.asarray([13, 24], jnp.int32)
     out = paged_flash_decode(q, k_pages, v_pages, tbl, lens, interpret=True)
 
-    k = k_pages[tbl].reshape(b, pmax * ps, h, d)
-    v = v_pages[tbl].reshape(b, pmax * ps, h, d)
+    k = k_pages[tbl].transpose(0, 1, 3, 2, 4).reshape(b, pmax * ps, h, d)
+    v = v_pages[tbl].transpose(0, 1, 3, 2, 4).reshape(b, pmax * ps, h, d)
     ref = plain_attention(q[:, None], k, v, causal=False, kv_len=lens)[:, 0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
@@ -89,7 +88,8 @@ def test_decode_empty_and_single_token_slots():
     np.testing.assert_allclose(np.asarray(out[0]), 0.0, atol=1e-7)
     # one valid token -> softmax weight 1 on it
     np.testing.assert_allclose(np.asarray(out[1]),
-                               np.asarray(v_pages[tbl[1, 0], 0]), atol=1e-6)
+                               np.asarray(v_pages[tbl[1, 0], :, 0]),
+                               atol=1e-6)
 
 
 def test_decode_mixed_zero_and_ragged_lens():
@@ -146,23 +146,6 @@ def test_decode_ignores_stale_table_entries():
     o1 = paged_flash_decode(q, k_pages, v_pages, t1, lens, interpret=True)
     o2 = paged_flash_decode(q, k_pages, v_pages, t2, lens, interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-7)
-
-
-def test_flash_attention_runs_without_pltpu(monkeypatch):
-    """Regression: with the TPU helpers unavailable the flash kernel's
-    scratch must still match its signature and run (interpret mode)."""
-    from repro.kernels import flash_attention as fa
-    monkeypatch.setattr(fa, "pltpu", None)
-    monkeypatch.setattr(fa, "_VMEM", None)
-    rng = np.random.default_rng(4)
-    q = jnp.asarray(rng.normal(size=(1, 1, 128, 64)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, 1, 128, 64)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, 1, 128, 64)), jnp.float32)
-    out = fa.flash_attention(q, k, v, causal=True)   # interpret forced
-    from repro.kernels.ref import ref_flash_attention
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref_flash_attention(q, k, v)),
-                               atol=2e-5, rtol=2e-5)
 
 
 def test_ops_dispatcher_paged_decode():

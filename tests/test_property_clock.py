@@ -1,9 +1,7 @@
 """Property tests for the virtual clock + Poisson loadgen (DESIGN.md §10).
 
-Runs under real hypothesis when installed, else the in-tree stub
-(tests/helpers/hypothesis_stub.py) registered by conftest. Pins the
-properties the e2e harness leans on: seed-deterministic arrival gaps,
-monotone non-decreasing times (including the translated ``start``
+Pins the properties the e2e harness leans on: seed-deterministic arrival
+gaps, monotone non-decreasing times (including the translated ``start``
 segments of requeued bursts), and insertion-order tie-breaks that hold
 under arbitrary interleavings of schedule and pop.
 """

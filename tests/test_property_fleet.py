@@ -1,9 +1,8 @@
 """Property tests for the phi-accrual failure detector (DESIGN.md §16).
 
-Runs under real hypothesis when installed, else the in-tree stub
-(tests/helpers/hypothesis_stub.py) registered by conftest. Pins the
-monotonicity contract the wall-clock monitor leans on: suspicion only
-accrues during silence when there is an outstanding expectation
+Pins the monotonicity contract the wall-clock monitor leans on:
+suspicion only accrues during silence when there is an outstanding
+expectation
 (``last_sent > last_seen``), it never decreases while the silence
 lasts, and a single observation resets it — across the window and
 min_samples edges where the gap model switches from the prior to the

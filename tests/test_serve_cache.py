@@ -128,12 +128,12 @@ def test_null_page_survives_idle_writes(qwen_setup):
     # slot 1 idle: its table rows are 0 -> appends land in the null page
     from repro.models.attention import _paged_append
     pos0 = next(i for i, k in enumerate(cfg.layer_pattern) if k == "attn")
-    pool = kv.cache[pos0]["mixer"]["k_pages"][0]      # (N, PS, n_kv, hd)
-    new = jnp.ones((2,) + pool.shape[2:], pool.dtype)
+    pool = kv.cache[pos0]["mixer"]["k_pages"][0]      # (N, n_kv, PS, hd)
+    new = jnp.ones((2, pool.shape[1], pool.shape[3]), pool.dtype)
     out = _paged_append(pool, new, kv.page_table_dev,
-                        jnp.asarray([11, 0], jnp.int32), 4)
+                        jnp.asarray([11, 0], jnp.int32), 4, head_major=True)
     # write for the idle row hit page 0
-    assert bool((out[0, 0] == 1).all())
+    assert bool((out[0, :, 0] == 1).all())
     blocks = list(kv.cache)
     blk = dict(blocks[pos0])
     blk["mixer"] = dict(blk["mixer"], k_pages=out[None].repeat(

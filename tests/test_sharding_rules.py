@@ -74,11 +74,11 @@ def test_cache_specs_pages_match_kernel_dispatch():
     must mirror tp_paged_decode's fallback, else the pools stay sharded
     while the kernel runs unsharded and every decode step all-gathers
     the pools."""
-    sds = jax.ShapeDtypeStruct((2, 16, 8, 4, 64), jax.numpy.bfloat16)
+    sds = jax.ShapeDtypeStruct((2, 16, 4, 8, 64), jax.numpy.bfloat16)
     cache = ({"mixer": {"k_pages": sds, "v_pages": sds}, "ffn": {}},)
     rules = MeshRules(fsdp_axes=(), axis_sizes={"model": 4})
     kp = cache_specs(rules, cache, n_query_heads=8)[0]["mixer"]["k_pages"]
-    assert kp == P(None, None, None, "model", None)   # 4 | Hkv=4, 4 | H=8
+    assert kp == P(None, None, "model", None, None)   # 4 | Hkv=4, 4 | H=8
     kp = cache_specs(rules, cache, n_query_heads=6)[0]["mixer"]["k_pages"]
     assert kp == P(None, None, None, None, None)      # 4 | Hkv but 4 ∤ H
     # multi-axis tp: never trim to a subgroup the kernel would not use
