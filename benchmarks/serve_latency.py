@@ -178,13 +178,26 @@ def _drain_ttft(engine, reqs, new_tokens: int):
 
     Every request is submitted before the drain starts, so TTFT folds in
     the queueing delay behind slower admissions — exactly the tail the
-    prefix cache is supposed to cut."""
+    prefix cache is supposed to cut. A first token is stamped when the
+    step that made it returns."""
     base = dict(engine.stats)
-    rids = [engine.submit(p, new_tokens) for p in reqs]
-    t0 = time.time()
-    out = engine.run()
-    wall = time.time() - t0
-    ttfts = np.asarray([engine.sched.finished[r].ttft for r in rids])
+    t_sub, states = {}, {}
+    for p in reqs:
+        rid = engine.submit(p, new_tokens)
+        t_sub[rid] = time.perf_counter()
+        states[rid] = engine.sched.waiting[-1]
+    rids = list(t_sub)
+    t0 = time.perf_counter()
+    first = {}
+    while not engine.sched.idle:
+        engine.step()
+        t = time.perf_counter()
+        for rid, st in states.items():
+            if rid not in first and st.generated:
+                first[rid] = t
+    wall = time.perf_counter() - t0
+    ttfts = np.asarray([first[r] - t_sub[r] for r in rids])
+    out = {r: np.asarray(states[r].generated, np.int32) for r in rids}
     return out, rids, wall, ttfts, base
 
 

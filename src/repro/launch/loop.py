@@ -92,26 +92,42 @@ class TrainLoop:
         self.hist = LoopHistory()
 
     def run(self, steps: int, log_every: int = 0) -> LoopHistory:
+        """``steps`` iterations of Algorithm 1. Each is a host span on the
+        profiler's clock, ``repro.train.step``, holding ``select`` (the
+        oracle's first n - r), ``feed`` (the batch, its weights and their
+        uploads), ``dispatch`` (the step call), ``sync`` (the three host
+        reads: loss, grad norm, step) and ``ckpt`` (a save, when due)."""
         for i in range(steps):
-            tokens, targets = next(self.data_iter)
-            mask, rt, full_rt = self.oracle.next_mask()
-            weights = mask_to_weights(mask, tokens.shape[0],
-                                      tokens.shape[1])
-            batch = {"tokens": jnp.asarray(tokens),
-                     "targets": jnp.asarray(targets),
-                     "weights": jnp.asarray(weights)}
-            self.state, metrics = self.step_fn(self.state, batch)
-            self.hist.loss.append(float(metrics["loss"]))
-            self.hist.grad_norm.append(float(metrics["grad_norm"]))
-            self.hist.round_time.append(rt)
-            self.hist.sync_round_time.append(full_rt)
-            step = int(self.state["step"])
-            if self.ckpt and self.ckpt_every and step % self.ckpt_every == 0:
-                self.ckpt.save(self.state, step)     # async, atomic
-            if log_every and (i + 1) % log_every == 0:
-                print(f"[loop] step {step:5d} loss {metrics['loss']:.4f} "
-                      f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"round {rt:.2f}s (sync {full_rt:.2f}s)", flush=True)
+            with jax.profiler.StepTraceAnnotation(
+                    "repro.train.step", step_num=len(self.hist.loss)):
+                with jax.profiler.TraceAnnotation("repro.train.select"):
+                    mask, rt, full_rt = self.oracle.next_mask()
+                with jax.profiler.TraceAnnotation("repro.train.feed"):
+                    tokens, targets = next(self.data_iter)
+                    weights = mask_to_weights(mask, tokens.shape[0],
+                                              tokens.shape[1])
+                    batch = {"tokens": jnp.asarray(tokens),
+                             "targets": jnp.asarray(targets),
+                             "weights": jnp.asarray(weights)}
+                with jax.profiler.TraceAnnotation("repro.train.dispatch"):
+                    self.state, metrics = self.step_fn(self.state, batch)
+                with jax.profiler.TraceAnnotation("repro.train.sync"):
+                    loss = float(metrics["loss"])
+                    grad_norm = float(metrics["grad_norm"])
+                    step = int(self.state["step"])
+                self.hist.loss.append(loss)
+                self.hist.grad_norm.append(grad_norm)
+                self.hist.round_time.append(rt)
+                self.hist.sync_round_time.append(full_rt)
+                if (self.ckpt and self.ckpt_every
+                        and step % self.ckpt_every == 0):
+                    with jax.profiler.TraceAnnotation("repro.train.ckpt"):
+                        self.ckpt.save(self.state, step)  # async, atomic
+                if log_every and (i + 1) % log_every == 0:
+                    print(f"[loop] step {step:5d} loss {loss:.4f} "
+                          f"gnorm {grad_norm:.3f} "
+                          f"round {rt:.2f}s (sync {full_rt:.2f}s)",
+                          flush=True)
         if self.ckpt:
             self.ckpt.save(self.state, int(self.state["step"]),
                            blocking=True)

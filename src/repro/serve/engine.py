@@ -23,6 +23,13 @@ the same way ``agg_backend="host"`` is for training (DESIGN.md §11).
 
 Greedy (argmax) decoding, matching the rest of the repo's drivers.
 
+Every layer boundary of a step is a host span on the profiler's clock
+(``jax.profiler.TraceAnnotation``, names ``repro.serve.*``, request ids
+as span stats): ``submit``; ``step``, holding ``admit`` (which holds
+``schedule``, ``prefill``, ``page_write`` and ``suffix``), ``schedule``
+for the preemption choice, ``decode`` and ``retire``. With the profiler
+off a span costs about a microsecond; nothing else here keeps time.
+
 MoE runs *drop-free* at inference (capacity_factor raised to
 num_experts / top_k, so capacity >= tokens-per-group always): capacity
 binning is a training-throughput trade-off, and at serving time dropping
@@ -33,12 +40,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ArchConfig
@@ -224,18 +231,20 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def submit(self, prompt, max_new_tokens: int, priority: int = 0,
                deadline: Optional[float] = None) -> int:
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prompt.size == 0:
-            raise ValueError("empty prompt")
-        if max_new_tokens < 1:
-            raise ValueError("need max_new_tokens >= 1")
         rid = self._next_rid
-        self._next_rid += 1
-        # an over-capacity request lands in sched.rejected (with reason)
-        # instead of raising — one bad request must not kill the stream
-        self.sched.submit(Request(rid=rid, prompt=prompt,
-                                  max_new_tokens=max_new_tokens,
-                                  priority=priority, deadline=deadline))
+        with TraceAnnotation("repro.serve.submit", rid=rid):
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            if prompt.size == 0:
+                raise ValueError("empty prompt")
+            if max_new_tokens < 1:
+                raise ValueError("need max_new_tokens >= 1")
+            self._next_rid += 1
+            # an over-capacity request lands in sched.rejected (with
+            # reason) instead of raising — one bad request must not kill
+            # the stream
+            self.sched.submit(Request(rid=rid, prompt=prompt,
+                                      max_new_tokens=max_new_tokens,
+                                      priority=priority, deadline=deadline))
         return rid
 
     @property
@@ -254,28 +263,32 @@ class ServeEngine:
         return pages_needed(st.req.total_len, self.ccfg.page_size)
 
     def _admit(self) -> None:
-        admitted = self.sched.admissions(self.kv.available_pages,
-                                         need_pages=self._need_pages)
-        if not admitted:
-            if not self.sched.active and self.sched.waiting:
-                raise RuntimeError(
-                    "head request can never be admitted (page pool too "
-                    "small even when idle)")
-            return
-        fresh = [st for st in admitted if st.swap is None]
-        resumed = [st for st in admitted if st.swap is not None]
-        for st in resumed:
-            self._resume(st)
-        self.stats["admitted"] += len(fresh)
-        if fresh:
-            if self.kv.prefix is None:
-                self._admit_grouped(fresh)
-            else:
-                for st in fresh:
-                    self._admit_prefix(st)
-        # keep the counter live for prefill-only workloads too — step()
-        # may never reach a decode that would otherwise refresh it
-        self.stats["table_uploads"] = self.kv.table_uploads
+        with TraceAnnotation("repro.serve.admit") as span:
+            with TraceAnnotation("repro.serve.schedule"):
+                admitted = self.sched.admissions(
+                    self.kv.available_pages, need_pages=self._need_pages)
+            span.set_metadata(n=len(admitted))
+            if not admitted:
+                if not self.sched.active and self.sched.waiting:
+                    raise RuntimeError(
+                        "head request can never be admitted (page pool "
+                        "too small even when idle)")
+                return
+            fresh = [st for st in admitted if st.swap is None]
+            resumed = [st for st in admitted if st.swap is not None]
+            for st in resumed:
+                self._resume(st)
+            self.stats["admitted"] += len(fresh)
+            if fresh:
+                if self.kv.prefix is None:
+                    self._admit_grouped(fresh)
+                else:
+                    for st in fresh:
+                        self._admit_prefix(st)
+            # keep the counter live for prefill-only workloads too —
+            # step() may never reach a decode that would otherwise
+            # refresh it
+            self.stats["table_uploads"] = self.kv.table_uploads
 
     def _admit_grouped(self, admitted: List[RequestState]) -> None:
         """The conformance admission path (prefix_cache="off"): batched
@@ -287,19 +300,27 @@ class ServeEngine:
             bucket = -(-s0 // ps) * ps if self._pad_buckets else s0
             groups.setdefault(bucket, []).append(st)
         for bucket, group in sorted(groups.items()):
-            prompts = np.zeros((len(group), bucket), np.int32)
-            for i, st in enumerate(group):
-                prompts[i, : st.req.prompt_len] = st.req.prompt
-            first, cache = self._prefill(self.params, jnp.asarray(prompts))
-            self.stats["prefill_calls"] += 1
-            first = np.asarray(first)
-            self.stats["host_syncs"] += 1
+            with TraceAnnotation(
+                    "repro.serve.prefill",
+                    rids=";".join(str(st.req.rid) for st in group),
+                    tokens=bucket * len(group)):
+                prompts = np.zeros((len(group), bucket), np.int32)
+                for i, st in enumerate(group):
+                    prompts[i, : st.req.prompt_len] = st.req.prompt
+                first, cache = self._prefill(self.params,
+                                             jnp.asarray(prompts))
+                self.stats["prefill_calls"] += 1
+                first = np.asarray(first)
+                self.stats["host_syncs"] += 1
             for i, st in enumerate(group):
                 s0 = st.req.prompt_len
-                one = jax.tree.map(lambda l, i=i: l[:, i:i + 1], cache)
-                # admit() scatters only the first s0 tokens of each page,
-                # so the causal-invisible right-pad never enters the cache
-                self.kv.admit(st.slot, one, s0, st.req.total_len)
+                with TraceAnnotation("repro.serve.page_write",
+                                     rid=st.req.rid):
+                    one = jax.tree.map(lambda l, i=i: l[:, i:i + 1], cache)
+                    # admit() scatters only the first s0 tokens of each
+                    # page, so the causal-invisible right-pad never
+                    # enters the cache
+                    self.kv.admit(st.slot, one, s0, st.req.total_len)
                 self._first_token(st, int(first[i, s0 - 1]))
 
     def _admit_prefix(self, st: RequestState) -> None:
@@ -318,27 +339,36 @@ class ServeEngine:
                 return
             s0 = req.prompt_len
             bucket = -(-s0 // ps) * ps if self._pad_buckets else s0
-            prompts = np.zeros((1, bucket), np.int32)
-            prompts[0, :s0] = req.prompt
-            first, cache = self._prefill(self.params, jnp.asarray(prompts))
-            self.stats["prefill_calls"] += 1
-            first = np.asarray(first)
-            self.stats["host_syncs"] += 1
-            one = jax.tree.map(lambda l: l[:, 0:1], cache)
-            self.kv.admit(st.slot, one, s0, req.total_len)
-            self.kv.register_prompt(st.slot, req.prompt)
+            with TraceAnnotation("repro.serve.prefill", rids=str(req.rid),
+                                 tokens=bucket):
+                prompts = np.zeros((1, bucket), np.int32)
+                prompts[0, :s0] = req.prompt
+                first, cache = self._prefill(self.params,
+                                             jnp.asarray(prompts))
+                self.stats["prefill_calls"] += 1
+                first = np.asarray(first)
+                self.stats["host_syncs"] += 1
+            with TraceAnnotation("repro.serve.page_write", rid=req.rid):
+                one = jax.tree.map(lambda l: l[:, 0:1], cache)
+                self.kv.admit(st.slot, one, s0, req.total_len)
+                self.kv.register_prompt(st.slot, req.prompt)
             self.stats["cache_miss_tokens"] += s0
             self._first_token(st, int(first[0, s0 - 1]))
             return
         try:
-            self.kv.admit_shared(st.slot, plan, req.total_len)
+            with TraceAnnotation("repro.serve.page_write", rid=req.rid):
+                self.kv.admit_shared(st.slot, plan, req.total_len)
         except MemoryError:
             self.sched.requeue(st)       # gate-time plan went stale
             return
         self.stats["cache_hit_tokens"] += plan.cached_len
         self.stats["cache_miss_tokens"] += req.prompt_len - plan.cached_len
-        first = self._feed_suffix(st.slot, req.prompt[plan.cached_len:])
-        self.kv.register_prompt(st.slot, req.prompt)
+        suffix = req.prompt[plan.cached_len:]
+        with TraceAnnotation("repro.serve.suffix", rid=req.rid,
+                             tokens=len(suffix)):
+            first = self._feed_suffix(st.slot, suffix)
+        with TraceAnnotation("repro.serve.page_write", rid=req.rid):
+            self.kv.register_prompt(st.slot, req.prompt)
         self._first_token(st, first)
 
     def _feed_suffix(self, slot: int, suffix) -> int:
@@ -374,8 +404,6 @@ class ServeEngine:
     def _first_token(self, st: RequestState, tok: int) -> None:
         st.pending = tok
         st.generated.append(tok)
-        if st.ttft is None:
-            st.ttft = time.monotonic() - st.t_submit
         if st.done:             # max_new_tokens == 1: no decode needed
             self._retire(st.slot)
 
@@ -384,8 +412,9 @@ class ServeEngine:
         generated stream survived on the host, so decode continues
         exactly where it stopped."""
         try:
-            self.kv.swap_in(st.slot, st.swap, st.req.prompt,
-                            st.req.total_len)
+            with TraceAnnotation("repro.serve.page_write", rid=st.req.rid):
+                self.kv.swap_in(st.slot, st.swap, st.req.prompt,
+                                st.req.total_len)
         except MemoryError:
             self.sched.requeue(st)
             return
@@ -399,7 +428,8 @@ class ServeEngine:
         iteration preempts one victim, so no livelock)."""
         guard = len(self.sched.active)
         while guard > 0:
-            slot = self.sched.preemption_victim()
+            with TraceAnnotation("repro.serve.schedule"):
+                slot = self.sched.preemption_victim()
             if slot is None:
                 return
             st = self.sched.active[slot]
@@ -514,72 +544,87 @@ class ServeEngine:
         runs K budget-bounded decode iterations in one jitted scan and
         talks to the host once at the boundary.
         """
-        self.sched.clock += 1.0
-        self._admit()
-        self._preempt()
-        self.stats["cow_forks"] = self.kv.cow_forks
-        self.stats["swapped_pages"] = self.kv.swapped_pages
-        if self.kv.prefix is not None:
-            self.stats["prefix_evictions"] = self.kv.prefix.evictions
-        if not self.sched.active:
-            return
-        if self.superstep_k == 1:
-            self._step_single()
-            return
-        k = self.sched.superstep_k(self.superstep_k)
-        if k == 0:      # pragma: no cover - active slots always have budget
-            return
-        toks = np.zeros((self.ccfg.num_slots,), np.int32)
-        remaining = np.zeros((self.ccfg.num_slots,), np.int32)
-        for slot, st in self.sched.active.items():
-            toks[slot] = st.pending
-            remaining[slot] = st.req.max_new_tokens - len(st.generated)
-        # page tables / lengths are cached device-side behind a dirty
-        # flag — a decode-only superstep re-uses them; the lens carry
-        # advances in-scan and is adopted back via commit_tokens
-        out, new_cache, new_lens = self._superstep(
-            self.params, jnp.asarray(toks), self.kv.cache,
-            self.kv.kv_lens_dev, self.kv.page_table_dev,
-            jnp.asarray(remaining), k=k)
-        self.stats["decode_steps"] += k
-        self.stats["supersteps"] += 1
-        self.kv.update(new_cache)
-        active = list(self.sched.active)
-        self.kv.commit_tokens(active, k, new_lens)
-        out = np.asarray(out)            # (K, B): the one boundary sync
-        self.stats["host_syncs"] += 1
-        self.stats["table_uploads"] = self.kv.table_uploads
-        for slot in active:
-            st = self.sched.active[slot]
-            st.generated.extend(int(t) for t in out[:, slot])
-            st.pending = int(out[-1, slot])
-            if st.done:
-                self._retire(slot)
+        with TraceAnnotation("repro.serve.step"):
+            self.sched.clock += 1.0
+            self._admit()
+            self._preempt()
+            self.stats["cow_forks"] = self.kv.cow_forks
+            self.stats["swapped_pages"] = self.kv.swapped_pages
+            if self.kv.prefix is not None:
+                self.stats["prefix_evictions"] = self.kv.prefix.evictions
+            if not self.sched.active:
+                return
+            if self.superstep_k == 1:
+                out = self._step_single()
+            else:
+                k = self.sched.superstep_k(self.superstep_k)
+                if k == 0:  # pragma: no cover - active slots have budget
+                    return
+                out = self._superstep_once(k)
+            self._append_and_retire(out)
 
-    def _step_single(self) -> None:
-        """The original one-token host loop (superstep_k=1 conformance)."""
-        toks = np.zeros((self.ccfg.num_slots, 1), np.int32)
-        for slot, st in self.sched.active.items():
-            toks[slot, 0] = st.pending
-        # page tables / lengths are cached device-side behind a dirty
-        # flag — a decode-only step re-uses them instead of re-uploading
-        nxt, new_cache = self._decode(
-            self.params, jnp.asarray(toks), self.kv.cache,
-            self.kv.kv_lens_dev, self.kv.page_table_dev)
-        self.stats["decode_steps"] += 1
-        self.stats["supersteps"] += 1
-        self.kv.update(new_cache)
+    def _superstep_once(self, k: int) -> np.ndarray:
+        """K decode iterations in one dispatch; returns the (K, B) tokens
+        after the one boundary sync."""
         active = list(self.sched.active)
-        self.kv.commit_token(active)     # each slot's pending token landed
-        nxt = np.asarray(nxt)
-        self.stats["host_syncs"] += 1
-        self.stats["table_uploads"] = self.kv.table_uploads
-        for slot in active:
-            st = self.sched.active[slot]
-            st.pending = int(nxt[slot])
-            st.generated.append(st.pending)
-            if st.done:
-                self._retire(slot)
+        with TraceAnnotation("repro.serve.decode", k=k, active=len(active)):
+            toks = np.zeros((self.ccfg.num_slots,), np.int32)
+            remaining = np.zeros((self.ccfg.num_slots,), np.int32)
+            for slot, st in self.sched.active.items():
+                toks[slot] = st.pending
+                remaining[slot] = st.req.max_new_tokens - len(st.generated)
+            # page tables / lengths are cached device-side behind a dirty
+            # flag — a decode-only superstep re-uses them; the lens carry
+            # advances in-scan and is adopted back via commit_tokens
+            out, new_cache, new_lens = self._superstep(
+                self.params, jnp.asarray(toks), self.kv.cache,
+                self.kv.kv_lens_dev, self.kv.page_table_dev,
+                jnp.asarray(remaining), k=k)
+            self.stats["decode_steps"] += k
+            self.stats["supersteps"] += 1
+            self.kv.update(new_cache)
+            self.kv.commit_tokens(active, k, new_lens)
+            out = np.asarray(out)            # (K, B): the one boundary sync
+            self.stats["host_syncs"] += 1
+            self.stats["table_uploads"] = self.kv.table_uploads
+        return out
+
+    def _step_single(self) -> np.ndarray:
+        """The original one-token host loop (superstep_k=1 conformance);
+        returns the (1, B) tokens."""
+        active = list(self.sched.active)
+        with TraceAnnotation("repro.serve.decode", k=1, active=len(active)):
+            toks = np.zeros((self.ccfg.num_slots, 1), np.int32)
+            for slot, st in self.sched.active.items():
+                toks[slot, 0] = st.pending
+            # page tables / lengths are cached device-side behind a dirty
+            # flag — a decode-only step re-uses them instead of
+            # re-uploading
+            nxt, new_cache = self._decode(
+                self.params, jnp.asarray(toks), self.kv.cache,
+                self.kv.kv_lens_dev, self.kv.page_table_dev)
+            self.stats["decode_steps"] += 1
+            self.stats["supersteps"] += 1
+            self.kv.update(new_cache)
+            self.kv.commit_token(active)  # each slot's pending token landed
+            nxt = np.asarray(nxt)
+            self.stats["host_syncs"] += 1
+            self.stats["table_uploads"] = self.kv.table_uploads
+        return nxt[None]
+
+    def _append_and_retire(self, out: np.ndarray) -> None:
+        """Append each active slot's column of ``out`` (K, B) to its
+        stream and retire the requests that are done."""
+        with TraceAnnotation("repro.serve.retire") as span:
+            n = 0
+            for slot in list(self.sched.active):
+                st = self.sched.active[slot]
+                st.generated.extend(int(t) for t in out[:, slot])
+                st.pending = int(out[-1, slot])
+                if st.done:
+                    self._retire(slot)
+                    n += 1
+            span.set_metadata(n=n)
 
     # ------------------------------------------------------------------
     def run(self, max_steps: int = 100_000) -> Dict[int, np.ndarray]:

@@ -32,7 +32,6 @@ the prefill/eviction/swap against the paged cache.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
@@ -68,8 +67,6 @@ class RequestState:
     generated: List[int] = dataclasses.field(default_factory=list)
     pending: Optional[int] = None       # produced but not yet in the cache
     arrival: float = 0.0                # scheduler clock at submit
-    t_submit: float = 0.0               # wall clock at submit
-    ttft: Optional[float] = None        # wall seconds submit -> 1st token
     swap: Optional[SwapState] = None    # host KV image while preempted
     preemptions: int = 0
 
@@ -112,8 +109,7 @@ class Scheduler:
                 f"{req.total_len} tokens need {need} pages > pool of "
                 f"{self.ccfg.num_pages - 1}")))
             return False
-        self.waiting.append(RequestState(req=req, arrival=self.clock,
-                                         t_submit=time.monotonic()))
+        self.waiting.append(RequestState(req=req, arrival=self.clock))
         return True
 
     def _score(self, st: RequestState):
