@@ -33,6 +33,23 @@ def flash_attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
+def causal_gqa_flash(q, k, v, *, impl: str = "auto"):
+    """Causal self-attention, KV heads grouped, forward and backward
+    (training and prefill). q: (B,S,H,D); k,v: (B,S,Hkv,D) with
+    H % Hkv == 0 and S % 128 == 0. Returns (B,S,H,D).
+
+    The kernel runs under the name scope ``repro.attn.flash``: its
+    forward and backward calls carry it in their op metadata, under the
+    kernel names splash gives them (``splash_mha_*``)."""
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+        return _ref.ref_causal_gqa_attention(q, k, v)
+    from repro.kernels import causal_flash as _cf
+    interpret = impl == "interpret" or not _on_tpu()
+    with jax.named_scope("repro.attn.flash"):
+        return _cf.causal_gqa_flash(q, k, v, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
                            impl: str = "auto"):
     """Single-query attention over paged KV (serving decode hot path).

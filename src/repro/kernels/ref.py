@@ -21,6 +21,22 @@ def ref_flash_attention(q, k, v, *, causal: bool = True):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+def ref_causal_gqa_attention(q, k, v):
+    """Causal self-attention with grouped KV heads (pure-jnp oracle).
+    q: (B,S,H,D); k,v: (B,S,Hkv,D), H % Hkv == 0 (query head h reads KV
+    head h // (H/Hkv)). Returns (B,S,H,D) in q.dtype, fp32 softmax."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    qg = q.astype(jnp.float32).reshape(b, s, hkv, h // hkv, d)
+    s_ = jnp.einsum("bskgd,btkd->bkgst", qg,
+                    k.astype(jnp.float32)) * (d ** -0.5)
+    mask = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    s_ = jnp.where(mask, s_, NEG_INF)
+    w = jax.nn.softmax(s_, axis=-1)
+    out = jnp.einsum("bkgst,btkd->bskgd", w, v.astype(jnp.float32))
+    return out.reshape(b, s, h, d).astype(q.dtype)
+
+
 def ref_paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens):
     """Single-query attention over a paged KV cache (pure-jnp oracle).
 

@@ -1,9 +1,10 @@
 """Attention: GQA (opt. QKV bias), DeepSeek MLA, cross-attention, KV cache.
 
-Long sequences use a chunked online-softmax ("flash" in pure JAX, scan over
-key blocks) so the (S,T) score matrix is never materialized — this is the
-roofline-path implementation; the Pallas kernel in ``repro.kernels`` computes
-the same math for TPU and is validated against it.
+Causal GQA self-attention on TPU (training, prefill) takes the Pallas
+flash kernel with a backward pass (``flash_gate``); elsewhere long
+sequences use a chunked online-softmax ("flash" in pure JAX, scan over key
+blocks) so the (S,T) score matrix is never materialized, and short ones
+the plain form.
 """
 from __future__ import annotations
 
@@ -13,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.dist.act_sharding import constrain
+from repro.dist.act_sharding import constrain, current_policy
 from repro.dist.sharding import current_serve_tp
 from repro.models.layers import apply_rope, dense_init, _dtype
 
@@ -87,6 +88,23 @@ def chunked_attention(q, k, v, *, causal: bool, chunk: int = CHUNK):
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(n))
     out = acc / jnp.maximum(l, 1e-30)[..., None].astype(q.dtype)
     return out.transpose(0, 2, 1, 3)             # (B,S,H,D)
+
+
+def flash_gate(q, k, v, *, causal: bool, kv_len=None) -> bool:
+    """Whether self-attention takes the causal GQA flash kernel
+    (``repro.kernels.ops.causal_gqa_flash``) on un-repeated K/V. Decided
+    on what the call shows: causal self-attention over the whole
+    sequence (no ``kv_len``), S a multiple of 128, equal q and v head
+    dims (not MLA), whole KV-head groups, no activation-sharding policy
+    and no serving TP mesh (GSPMD cannot partition a ``pallas_call``; it
+    would need a ``shard_map``), and a TPU backend. Every other call
+    takes ``attention_math``."""
+    from repro.kernels import ops
+    s, h = q.shape[1], q.shape[2]
+    return (causal and kv_len is None and k.shape[1] == s
+            and s % 128 == 0 and v.shape[-1] == q.shape[-1]
+            and h % k.shape[2] == 0 and current_policy() is None
+            and current_serve_tp() is None and ops._on_tpu())
 
 
 def attention_math(q, k, v, *, causal: bool, kv_len=None):
@@ -263,8 +281,12 @@ def apply_gqa(p, x, cfg: ArchConfig, *, positions=None, kv_x=None,
             k = apply_rope(k, positions, cfg.rope_theta,
                            cfg.mrope_sections if cfg.rope == "mrope" else None)
         qh = constrain(q, "heads4")
-        out = attention_math(qh, expand_kv(k), expand_kv(v),
-                             causal=(causal and not cross))
+        if flash_gate(qh, k, v, causal=(causal and not cross)):
+            from repro.kernels import ops
+            out = ops.causal_gqa_flash(qh, k, v)
+        else:
+            out = attention_math(qh, expand_kv(k), expand_kv(v),
+                                 causal=(causal and not cross))
         if cross:
             new_cache = {"ck": k, "cv": v} if return_cache else None
         else:

@@ -172,9 +172,12 @@ class ServeEngine:
                 return cch
 
         def _prefill(params, tokens):
-            logits, _, cache = apply_model(params, tokens, cfg,
-                                           mode="prefill",
-                                           remat_policy="none")
+            # under a mesh the context keeps prefill off the Pallas flash
+            # kernel, which GSPMD cannot partition
+            with _tp():
+                logits, _, cache = apply_model(params, tokens, cfg,
+                                               mode="prefill",
+                                               remat_policy="none")
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
         def _decode(params, tokens, cache, lens, tbl):

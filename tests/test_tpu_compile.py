@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.ledger import ledger_width
-from repro.kernels import agg, decode_attention
+from repro.kernels import agg, causal_flash, decode_attention
 
 QWEN_P = 494_032_768          # qwen2-0.5b's parameter count
 LENET_P = 431_080             # the paper's LeNet
@@ -88,3 +88,20 @@ def test_agg_kernel_compiles_at_ledger_width(one_chip, kernel, n, p):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not re.search(r"\bpad\(", text)
+
+
+@pytest.mark.parametrize("b,s,h,hkv,d,grad", [
+    (24, 1024, 14, 2, 64, True),       # qwen2-0.5b training step
+    (1, 1024, 12, 2, 128, False),      # qwen2-1.5b prefill bucket
+], ids=["qwen2-0.5b_train", "qwen2-1.5b_prefill"])
+def test_causal_gqa_flash_compiles(one_chip, b, s, h, hkv, d, grad):
+    """The splash forward, and for training its fused backward kernel,
+    at the model's GQA geometry: two Mosaic calls with grad."""
+    bf = jnp.bfloat16
+    fwd = causal_flash.causal_gqa_flash
+    fn = (jax.grad(lambda q, k, v: jnp.sum(fwd(q, k, v).astype(jnp.float32)),
+                   argnums=(0, 1, 2)) if grad else fwd)
+    compiled = _compile(fn, _sds((b, s, h, d), bf, one_chip),
+                        _sds((b, s, hkv, d), bf, one_chip),
+                        _sds((b, s, hkv, d), bf, one_chip))
+    assert compiled.as_text().count("tpu_custom_call") >= (2 if grad else 1)
