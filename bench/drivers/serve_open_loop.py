@@ -41,8 +41,7 @@ import time
 import jax
 import numpy as np
 
-from bench import data, flops, weights
-from bench.run import load_module, BENCH
+from bench import data, weights
 
 
 class Req:
@@ -103,9 +102,8 @@ def run(ctx) -> dict:
     spec, seed = ctx.spec, ctx.seed
     cfg = dict(spec.config, **ctx.hooks.get("config", {}))
     tr = dict(spec.traffic, **ctx.hooks.get("traffic", {}))
-    arch = weights.arch_config(cfg, resize="config" in ctx.hooks)
-    ref = load_module(f"{BENCH}/references/{cfg['reference']}.py",
-                      "bench_ref_" + cfg["reference"])
+    fam, ref = spec.family, spec.reference
+    arch = fam.arch_config(cfg, resize="config" in ctx.hooks)
     T = ctx.seconds
     sched = data.schedule(tr, T, seed)
     prompts = data.prompt_tokens(seed, [a.prompt_len for a in sched],
@@ -113,7 +111,7 @@ def run(ctx) -> dict:
     reqs = [Req(a, p) for a, p in zip(sched, prompts)]
     control = ctx.check == "control"
 
-    params = weights.make_params(arch, seed)
+    params = weights.make_params(fam, arch, seed)
     eng = build_engine(arch, tr, params)
     ctx.hooks.get("engine", lambda _: None)(eng)
     warm(eng, tr, [a.prompt_len for a in sched], arch.vocab_size, seed)
@@ -168,7 +166,7 @@ def run(ctx) -> dict:
             g1 = len(q.state.generated)
             if g0 == 0 and g1 > 0:
                 counters["prompt_tokens"] += q.arr.prompt_len
-                counters["prefill_flops"] += flops.prefill_flops(
+                counters["prefill_flops"] += fam.prefill_flops(
                     cfg, q.arr.prompt_len)
             dec = g1 - max(g0, 1)
             if dec > 0:
@@ -179,9 +177,7 @@ def run(ctx) -> dict:
                 counters["decode_tokens"] += dec
                 counters["attn_queries"] += dec
                 counters["attn_kv"] += kv
-                counters["decode_flops"] += (
-                    2 * flops.matmul_params(cfg) * dec
-                    + flops.attn_pair_flops(cfg) * kv)
+                counters["decode_flops"] += fam.decode_flops(cfg, dec, kv)
 
     def step() -> None:
         on = traced["state"] == "on"
@@ -259,7 +255,7 @@ def run(ctx) -> dict:
         c = ref.frozen(ref.consts(cfg))
         L = tr["prompt"]["max"] + tr["output"]["max"]
         n_out = tr["output"]["max"]
-        rparams = weights.make_params(arch, seed)
+        rparams = weights.make_params(fam, arch, seed)
         t_ref = time.perf_counter()
         all_gaps, all_ctrl = [], []
         for q in sample:

@@ -38,8 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import data, flops, trace, weights
-from bench.run import load_module, BENCH
+from bench import data, trace, weights
 
 
 class HarnessLatency:
@@ -146,13 +145,12 @@ def run(ctx) -> dict:
     spec, seed = ctx.spec, ctx.seed
     cfg = dict(spec.config, **ctx.hooks.get("config", {}))
     tr = dict(spec.traffic, **ctx.hooks.get("traffic", {}))
-    arch = weights.arch_config(cfg, resize="config" in ctx.hooks)
+    fam, ref = spec.family, spec.reference
+    arch = fam.arch_config(cfg, resize="config" in ctx.hooks)
     B, S = tr["global_batch"], tr["seq"]
     n, r = tr["n_agents"], tr["r"]
     opt = tr["optimizer"]
     n_check = tr["check_steps"]
-    ref = load_module(f"{BENCH}/references/{cfg['reference']}.py",
-                      "bench_ref_" + cfg["reference"])
 
     rows = data.markov_rows(data.rng_for(seed, 3), tr["pool_batches"] * B,
                             S + 1, arch.vocab_size, **tr["markov"])
@@ -169,7 +167,7 @@ def run(ctx) -> dict:
                                                 seed=seed),
                          max_pos=S, seed=seed)
         loop.state = None              # the program's own init, freed
-        params = weights.make_params(arch, seed)
+        params = weights.make_params(fam, arch, seed)
         loop.state = {"params": params,
                       "opt": make_optimizer(tc).init(params),
                       "step": jnp.zeros((), jnp.int32)}
@@ -182,7 +180,7 @@ def run(ctx) -> dict:
         readings["m1"] = host_leaves(loop.state["opt"]["m"])
         hist = loop.run(n_check - 1)
         readings["loss"] = np.asarray(hist.loss[:n_check], np.float64)
-        p0 = weights.make_params(arch, seed)
+        p0 = weights.make_params(fam, arch, seed)
         readings["change"] = np.asarray(_change_norms(
             loop.state["params"], p0), np.float64)
         del p0
@@ -220,7 +218,7 @@ def run(ctx) -> dict:
         """The checked steps in float32 (or int8 with ``quant``). With
         ``compare`` (first moments after step 1, on the host), also the
         per-leaf norm of their difference from this run's."""
-        p = weights.make_params(arch, seed)
+        p = weights.make_params(fam, arch, seed)
         m, v = ref.zeros_f32(p), ref.zeros_f32(p)
         out = {"loss": []}
         for i in range(n_check):
@@ -240,7 +238,7 @@ def run(ctx) -> dict:
                     out["grad_diff"] = diff_norms(compare, m) / (
                         1.0 - opt["b1"])
         del m, v
-        p0 = weights.make_params(arch, seed)
+        p0 = weights.make_params(fam, arch, seed)
         out["change"] = np.asarray(_change_norms(p, p0), np.float64)
         out["loss"] = np.asarray(out["loss"])
         del p, p0
@@ -274,7 +272,7 @@ def run(ctx) -> dict:
     if check:
         peaks = ctx.hooks.get("peaks") or trace.peaks_for(
             jax.devices()[0].device_kind)
-        metrics["mfu"] = 100.0 * tps * flops.train_flops_per_token(cfg, S) \
+        metrics["mfu"] = 100.0 * tps * fam.train_flops_per_token(cfg, S) \
             / (spec.chips * peaks["bf16_flops_per_s"])
     return {
         "metrics": metrics,

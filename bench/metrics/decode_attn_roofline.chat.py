@@ -1,8 +1,8 @@
 """Roofline share of the Pallas paged decode-attention kernel: the least
-time its needed work takes on this chip (bytes of the keys and values it
+time its needed work takes on this chip (bytes of the cache entries it
 must read, and the query and output, or its FLOPs, whichever bound is
-larger; ``bench/flops.py``) over the kernel's device time in the traced
-window. The driver counts the decode positions and the keys each
+larger, as the cell's family counts them) over the kernel's device time
+in the traced window. The driver counts the decode positions and the keys each
 attends over."""
 import sys
 
@@ -19,9 +19,8 @@ def read(reduced, counters, spec):
     _, ns = op_time_ns(reduced, KERNEL)
     if not peaks or not ns or not counters.get("attn_queries"):
         return None
-    work = flops.paged_decode_attention(spec.config,
-                                        counters["attn_queries"],
-                                        counters["attn_kv"])
+    work = spec.family.paged_decode_attention(
+        spec.config, counters["attn_queries"], counters["attn_kv"])
     r = flops.roofline_share(work["flops"], work["bytes"], ns * 1e-9, peaks)
     print(f"decode_attn_roofline: {r['bound']}-bound, kernel "
           f"{ns * 1e-9!r} s, {work}", file=sys.stderr)

@@ -1,6 +1,6 @@
 """Model FLOPs of the prompt and decode tokens served in the traced
-window (``bench/flops.py``, counted by the driver) over the window's
-length times the chip's peak bf16 rate."""
+window (the cell's family's counts, summed by the driver) over the
+window's length times the chip's peak bf16 rate."""
 
 
 def read(reduced, counters, spec):

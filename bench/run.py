@@ -3,13 +3,29 @@
 
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Everything a cell is made of is found by name, from files of its own:
-``BENCHMARK.json`` (next to ``bench/``) names the cell's configuration and
-traffic mix; ``bench/configs/<config>.json`` holds the model's sizes,
-``bench/traffic/<traffic>.json`` the mix and the driver that runs it,
-``bench/drivers/<driver>.py`` the driver, and ``bench/metrics/<metric>.py``
-the reader of each per-layer metric. A new cell, mix, driver or metric is
-new files and new entries; nothing here changes.
+Everything a cell is made of is found by name, from files of its own
+under the checkout's ``bench/``:
+
+- ``BENCHMARK.json`` (next to ``bench/``) names the cell's configuration
+  and traffic mix;
+- a configuration is its file of sizes, ``bench/configs/<config>.json``,
+  whose ``"reference"`` key names its family. The family module
+  ``bench/families/<family>.py`` says what the harness knows of that
+  architecture: ``arch_config(cfg, resize=False)`` (the program's
+  configuration, checked against the file), ``shapes(arch)`` and
+  ``init(path, x)`` (the weights, ``bench/weights.py``), and, by the
+  rules of ``bench/flops.py``, the counts
+  ``train_flops_per_token(cfg, seq)``, ``prefill_flops(cfg, prompt_len)``,
+  ``decode_flops(cfg, tokens, kv)`` and
+  ``paged_decode_attention(cfg, queries, kv_tokens)`` (FLOPs and bytes).
+  The plain reference ``bench/references/<family>.py`` is the architecture
+  in float32 ``jax.numpy``, which the drivers compare with;
+- ``bench/traffic/<traffic>.json`` holds the mix and names the driver
+  that runs it, ``bench/drivers/<driver>.py``;
+- ``bench/metrics/<metric>.py`` is the reader of each per-layer metric.
+
+A new cell, configuration, family, mix, driver or metric is new files and
+new entries; nothing here changes.
 
 A run makes its weights and inputs from ``--seed``, warms every shape its
 traffic uses (set-up), measures for ``--seconds``, checks what the timed
@@ -42,9 +58,8 @@ import shutil                                              # noqa: E402
 import sys                                                 # noqa: E402
 import types                                               # noqa: E402
 
-BENCH = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(BENCH)
-OUT = os.path.join(ROOT, ".bench_out")         # traces; git-ignored
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+OUT = ".bench_out"                    # traces, in the checkout; git-ignored
 CACHE = os.path.join(ROOT, ".jax_cache")       # fixed: part of the key
 sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
 # libtpu would otherwise log to a fixed path under /tmp
@@ -56,13 +71,21 @@ def log(msg: str) -> None:
 
 
 def load_module(path: str, name: str):
-    """Import ``path`` (a file under ``bench/``) as a fresh module."""
+    """Import ``path`` as a fresh module."""
     if not os.path.isfile(path):
         raise FileNotFoundError(path)
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_part(root: str, kind: str, name: str):
+    """``<root>/bench/<kind>/<name>.py``: a driver, family, reference or
+    metric reader, found by its name."""
+    return load_module(os.path.join(root, "bench", kind, name + ".py"),
+                       "_".join(("bench", kind, name)).replace(
+                           ".", "_").replace("-", "_"))
 
 
 def load_json(path: str):
@@ -72,8 +95,9 @@ def load_json(path: str):
 
 def cell_spec(workload: str, root: str = ROOT) -> types.SimpleNamespace:
     """Everything ``BENCHMARK.json`` and the cell's files say about one
-    workload: its entry, configuration, traffic mix, driver and the
-    end-to-end and per-layer metrics it reports."""
+    workload: its entry, configuration with its family and reference,
+    traffic mix, driver and the end-to-end and per-layer metrics it
+    reports."""
     bench = load_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -93,7 +117,10 @@ def cell_spec(workload: str, root: str = ROOT) -> types.SimpleNamespace:
              if (workload in m["workloads"] if "workloads" in m
                  else m["moves"] in e2e_names)]
     return types.SimpleNamespace(
-        name=workload, cell=cell, config=config, traffic=traffic,
+        name=workload, root=root, cell=cell, config=config,
+        family=load_part(root, "families", config["reference"]),
+        reference=load_part(root, "references", config["reference"]),
+        traffic=traffic,
         chips=int(cell["chips"]), end_to_end=e2e, per_layer=layer,
         driver=traffic["driver"], run_seconds=bench["run_seconds"])
 
@@ -185,9 +212,7 @@ def per_layer_metrics(spec, reduced, counters) -> dict:
     nothing to read returns None and the metric is left out."""
     out = {}
     for m in spec.per_layer:
-        path = os.path.join(BENCH, "metrics", m["name"] + ".py")
-        reader = load_module(path, "bench_metric_" + m["name"].replace(
-            ".", "_").replace("-", "_"))
+        reader = load_part(spec.root, "metrics", m["name"])
         value = reader.read(reduced, counters, spec)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
@@ -251,10 +276,9 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
     ctx = Context(spec, args.seed, args.seconds, bool(args.trace),
                   args.check, T_START if require_tpu else
                   time.perf_counter(),
-                  os.path.join(root, ".bench_out", "trace"))
+                  os.path.join(root, OUT, "trace"))
     ctx.hooks = driver_hooks or {}
-    driver = load_module(os.path.join(BENCH, "drivers", spec.driver + ".py"),
-                         "bench_driver_" + spec.driver)
+    driver = load_part(root, "drivers", spec.driver)
     res = driver.run(ctx)
 
     reduced = None
@@ -264,7 +288,8 @@ def main(argv=None, *, require_tpu: bool = True, root: str = ROOT,
         reduced = T.reduce_dir(ctx.trace_dir, chips=spec.chips)
         reduced["peaks"] = peaks
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
-        with open(os.path.join(OUT, f"trace-{spec.name}.json"), "w") as f:
+        with open(os.path.join(root, OUT, f"trace-{spec.name}.json"),
+                  "w") as f:
             json.dump({"reduced": reduced, "counters": res["counters"]}, f)
     dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,

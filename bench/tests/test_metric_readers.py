@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from bench import flops, run, trace
+from bench import run, trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -66,7 +66,7 @@ def test_decode_roofline_and_serve_mfu():
         "count": 28, "ns": 2e6, "self_ns": 2e6, "label": label}})
     counters = {"attn_queries": 64, "attn_kv": 64 * 500,
                 "prefill_flops": 1e12, "decode_flops": 1e11}
-    work = flops.paged_decode_attention(spec.config, 64, 64 * 500)
+    work = spec.family.paged_decode_attention(spec.config, 64, 64 * 500)
     want = 100 * work["bytes"] / PEAKS["hbm_bytes_per_s"] / 2e-3
     got = reader("decode_attn_roofline.chat").read(r, counters, spec)
     assert got == pytest.approx(want) and 0 < got <= 100

@@ -1,12 +1,13 @@
 """The trace reduction on a small synthetic trace, the peak table, and the
-FLOP and byte counts against numbers worked out by hand for qwen2-0.5b."""
+``qwen2`` family's FLOP and byte counts against numbers worked out by
+hand for qwen2-0.5b."""
 import json
 import os
 
 import pytest
 from jax.profiler import ProfileData
 
-from bench import flops, trace
+from bench import flops, run, trace
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -124,27 +125,35 @@ def test_peaks_table():
 
 
 @pytest.fixture(scope="module")
+def qwen2():
+    return run.load_part(os.path.dirname(BENCH), "families", "qwen2")
+
+
+@pytest.fixture(scope="module")
 def qwen05():
     with open(os.path.join(BENCH, "configs", "qwen2-0.5b.json")) as f:
         return json.load(f)
 
 
-def test_qwen2_0_5b_counts(qwen05):
+def test_qwen2_0_5b_counts(qwen2, qwen05):
     # per layer: q 896*896 + k, v 2*896*128 + o 896*896 + 3*896*4864
-    assert flops.layer_matmul_params(qwen05) == 14_909_440
+    assert qwen2.layer_matmul_params(qwen05) == 14_909_440
     # 24 layers + the tied head 151,936 * 896; with the 27,648 bias and
     # 43,904 norm weights this is the model's 494,032,768 parameters
-    assert flops.matmul_params(qwen05) == 493_961_216
+    assert qwen2.matmul_params(qwen05) == 493_961_216
     # 6N + 3 * (4 * 14 * 64 * 24) * (1024 + 1) / 2
-    assert flops.train_flops_per_token(qwen05, 1024) == 3_096_016_896
+    assert qwen2.train_flops_per_token(qwen05, 1024) == 3_096_016_896
     # 2 * 24 layers * s + one head row + pairs s(s+1)/2 * 86,016
-    assert flops.prefill_flops(qwen05, 100) == (
+    assert qwen2.prefill_flops(qwen05, 100) == (
         2 * 357_826_560 * 100 + 2 * 136_134_656 + 86_016 * 5050)
-    assert flops.decode_flops(qwen05, 10) == 2 * 493_961_216 + 860_160
+    assert qwen2.decode_flops(qwen05, 1, 10) == 2 * 493_961_216 + 860_160
+    # three tokens that attend over 10, 11 and 12 keys
+    assert qwen2.decode_flops(qwen05, 3, 33) == \
+        3 * 2 * 493_961_216 + 33 * 86_016
 
 
-def test_paged_decode_bytes_and_roofline(qwen05):
-    w = flops.paged_decode_attention(qwen05, queries=2, kv_tokens=300)
+def test_paged_decode_bytes_and_roofline(qwen2, qwen05):
+    w = qwen2.paged_decode_attention(qwen05, queries=2, kv_tokens=300)
     # keys and values: 2 * 2 kv heads * 64 * 300 tokens * 2 B * 24 layers
     # query and output: 2 * 14 * 64 * 2 positions * 2 B * 24 layers
     assert w["bytes"] == 2 * 2 * 64 * 300 * 2 * 24 + 2 * 14 * 64 * 2 * 2 * 24

@@ -1,98 +1,24 @@
-"""Weights and configurations for the Qwen2 family, made by the harness.
+"""Weights made by the harness, in the layout of a configuration's family.
 
 The benchmark makes its weights itself, from ``--seed``, on the device,
 in one jitted call, in the dtype they are served in. The program gets
 them through its normal entry points; the plain reference makes them
 again from the same seed, so it takes nothing that the program made.
 
-The tree is the program's parameter layout (``repro.models.model``):
-layers stacked on a leading axis, one pattern position. ``arch_config``
-checks that the registry's configuration has the sizes of the file, and
-``make_params`` that its tree has the program's shapes, so a change on
-either side fails here rather than measuring another model.
+A family module (``bench/families/<name>.py``) says what the weights
+are: ``shapes(arch)`` gives the tree's shapes and ``init(path, x)``
+each leaf's initial values from a standard normal draw. Each leaf
+draws from its own key, the run's weights key folded with the leaf's
+index in the sorted order of the tree's paths. ``make_params`` checks
+that the tree has the program's shapes (``repro.models.model``), so a
+change on either side fails here rather than measuring another model.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
-
-EMBED_STD = 0.02
-BIAS_STD = 0.02
-NORM_STD = 0.1
-
-# configuration-file key -> ArchConfig field
-ARCH_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-             "num_hidden_layers": "n_layers",
-             "num_attention_heads": "n_heads",
-             "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
-             "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-             "tie_word_embeddings": "tie_embeddings"}
-
-
-def arch_config(cfg: dict, resize: bool = False):
-    """The program's ``ArchConfig`` for a configuration file, checked
-    against the file's sizes. ``resize`` (the harness's tests only) takes
-    the file's sizes instead of checking them."""
-    from repro.configs.registry import get_config
-    arch = get_config(cfg["registry"])
-    if resize:
-        arch = dataclasses.replace(arch, **{f: cfg[k]
-                                            for k, f in ARCH_KEYS.items()})
-    for key, field in ARCH_KEYS.items():
-        if getattr(arch, field) != cfg[key]:
-            raise ValueError(f"{cfg['name']}: registry {field}="
-                             f"{getattr(arch, field)!r}, configuration "
-                             f"file {key}={cfg[key]!r}")
-    if (arch.param_dtype, arch.compute_dtype) != (cfg["dtype"],) * 2:
-        raise ValueError(f"{cfg['name']}: registry dtypes "
-                         f"{arch.param_dtype}/{arch.compute_dtype}, "
-                         f"file {cfg['dtype']}")
-    if arch.layer_pattern != ("attn",) or not arch.qkv_bias:
-        raise ValueError(f"{cfg['name']}: not a Qwen2 dense GQA layout")
-    return arch
-
-
-def sizes(arch) -> dict:
-    return dict(d=arch.d_model, h=arch.n_heads, hkv=arch.n_kv_heads,
-                hd=arch.resolved_head_dim, ff=arch.d_ff, L=arch.n_layers,
-                V=arch.vocab_size)
-
-
-def shapes(arch) -> dict:
-    s = sizes(arch)
-    d, h, hkv, hd, ff, L, V = (s[k] for k in ("d", "h", "hkv", "hd", "ff",
-                                              "L", "V"))
-    return {
-        "embed": {"tok": (V, d)},
-        "blocks": ({
-            "norm1": {"scale": (L, d)},
-            "norm2": {"scale": (L, d)},
-            "mixer": {"wq": (L, d, h * hd), "wk": (L, d, hkv * hd),
-                      "wv": (L, d, hkv * hd), "wo": (L, h * hd, d),
-                      "bq": (L, h * hd), "bk": (L, hkv * hd),
-                      "bv": (L, hkv * hd)},
-            "ffn": {"w_gate": (L, d, ff), "w_up": (L, d, ff),
-                    "w_down": (L, ff, d)},
-        },),
-        "norm_f": {"scale": (d,)},
-    }
-
-
-def _leaf(key, path: str, shape, dtype):
-    name = path.rsplit("/", 1)[-1]
-    x = jax.random.normal(key, shape, jnp.float32)
-    if name == "tok":
-        x = x * EMBED_STD
-    elif name == "scale":
-        x = 1.0 + NORM_STD * x
-    elif name.startswith("b"):
-        x = x * BIAS_STD
-    else:                                   # (L, fan_in, fan_out) matmul
-        x = x * shape[-2] ** -0.5
-    return x.astype(dtype)
 
 
 def _paths(tree, prefix=""):
@@ -116,14 +42,18 @@ def _build(tree, fn, prefix=""):
 
 
 @functools.lru_cache(maxsize=8)
-def _maker(arch):
-    shp = shapes(arch)
+def _maker(family, arch):
+    shp = family.shapes(arch)
     dtype = jnp.dtype(arch.param_dtype)
     index = {p: i for i, (p, _) in enumerate(_paths(shp))}
 
+    def leaf(key, path, shape):
+        x = jax.random.normal(key, shape, jnp.float32)
+        return family.init(path, x).astype(dtype)
+
     def make(key):
-        return _build(shp, lambda p, s: _leaf(
-            jax.random.fold_in(key, index[p]), p, s, dtype))
+        return _build(shp, lambda p, s: leaf(
+            jax.random.fold_in(key, index[p]), p, s))
     return jax.jit(make)
 
 
@@ -135,9 +65,9 @@ def seed_key(seed: int, stream: int):
 WEIGHTS_STREAM = 1
 
 
-def make_params(arch, seed: int):
+def make_params(family, arch, seed: int):
     """The cell's weights, on the default device, from ``seed``."""
-    params = _maker(arch)(seed_key(seed, WEIGHTS_STREAM))
+    params = _maker(family, arch)(seed_key(seed, WEIGHTS_STREAM))
     check_layout(arch, params)
     return params
 
