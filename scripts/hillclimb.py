@@ -89,10 +89,6 @@ def main():
     # policy "dots" saves matmul outputs, trading HBM for flops.
     go("dsv2", "deepseek-v2-236b", "train_4k", "remat_dots",
        tc_kw={"remat_policy": "dots"})
-    # hypothesis B: capacity_factor 1.25 pads expert buffers; 1.0 cuts
-    # dispatch buffer traffic ~20% at mild drop rates.
-    go("dsv2", "deepseek-v2-236b", "train_4k", "cap10",
-       cfg_patch={"moe.capacity_factor": 1.0})
 
 
 if __name__ == "__main__":

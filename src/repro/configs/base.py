@@ -14,24 +14,50 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
+    """Token-choice experts, drop-free (``repro.models.moe``).
+
+    The router scores all ``num_experts``; the layer holds and computes
+    ``held`` of them, ``first_held`` onwards (one chip's share under
+    expert parallelism; ``None`` holds them all)."""
     num_experts: int
     top_k: int
     d_ff_expert: int
     num_shared_experts: int = 0
     dense_residual: bool = False      # arctic: dense MLP in parallel with MoE
-    capacity_factor: float = 1.25
     every_k_layers: int = 1           # MoE on layers where (idx % k == k-1); 2 for jamba
-    router_dtype: str = "float32"
+    router_dtype: str = "float32"     # routing math; the weight is stored in param_dtype
+    held: Optional[int] = None
+    first_held: int = 0
+    norm_topk_prob: bool = True       # renormalise the top-k scores to sum 1
+    routed_scaling_factor: float = 1.0
+    aux_coef: float = 0.01            # sequence-wise balance loss alpha
+
+    @property
+    def n_held(self) -> int:
+        return self.num_experts if self.held is None else self.held
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling``, type yarn)."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 multi-head latent attention."""
+    """DeepSeek-V2 multi-head latent attention. ``q_lora_rank`` None:
+    queries are one plain projection (DeepSeek-V2-Lite)."""
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    yarn: Optional[YarnConfig] = None
 
 
 @dataclass(frozen=True)
@@ -68,6 +94,9 @@ class ArchConfig:
     # Repeating block pattern, length = period. e.g. jamba:
     # ("mamba",)*4 + ("attn",) + ("mamba",)*3
     layer_pattern: Tuple[str, ...] = ("attn",)
+    # leading attention layers with a dense MLP, before the periods
+    # (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -100,10 +129,11 @@ class ArchConfig:
 
     @property
     def n_periods(self) -> int:
-        assert self.n_layers % self.period == 0, (
-            f"{self.name}: n_layers={self.n_layers} not divisible by "
-            f"period={self.period}")
-        return self.n_layers // self.period
+        body = self.n_layers - self.first_k_dense
+        assert body % self.period == 0, (
+            f"{self.name}: n_layers={self.n_layers} less first_k_dense="
+            f"{self.first_k_dense} not divisible by period={self.period}")
+        return body // self.period
 
     @property
     def sub_quadratic(self) -> bool:
@@ -116,6 +146,8 @@ class ArchConfig:
         return True  # all assigned archs have a decoder
 
     def moe_on_layer(self, idx: int) -> bool:
+        """Whether layer ``idx`` of the periods (leading dense layers not
+        counted) has experts."""
         if self.moe is None:
             return False
         k = self.moe.every_k_layers
@@ -130,12 +162,14 @@ class ArchConfig:
             moe = dataclasses.replace(
                 moe, num_experts=min(4, moe.num_experts),
                 top_k=min(2, moe.top_k), d_ff_expert=64,
-                num_shared_experts=min(1, moe.num_shared_experts))
+                num_shared_experts=min(1, moe.num_shared_experts),
+                held=None, first_held=0)
         mla = self.mla
         if mla is not None:
-            mla = MLAConfig(kv_lora_rank=32, q_lora_rank=48,
+            mla = MLAConfig(kv_lora_rank=32,
+                            q_lora_rank=48 if mla.q_lora_rank else None,
                             qk_nope_head_dim=16, qk_rope_head_dim=8,
-                            v_head_dim=16)
+                            v_head_dim=16, yarn=mla.yarn)
         ssm = self.ssm
         if ssm is not None:
             ssm = SSMConfig(d_state=8, d_conv=4, expand=2, dt_rank=8)
@@ -144,7 +178,8 @@ class ArchConfig:
             rwkv = RWKVConfig(head_dim=16, decay_lora=8, ddlerp_lora=8)
         return dataclasses.replace(
             self,
-            n_layers=period if not self.encoder_decoder else 2,
+            n_layers=(period + self.first_k_dense
+                      if not self.encoder_decoder else 2),
             encoder_layers=2 if self.encoder_decoder else 0,
             d_model=64,
             n_heads=4,

@@ -36,6 +36,6 @@ def _load_all() -> None:
     # importing each module registers its config
     from repro.configs import (  # noqa: F401
         jamba_v0_1_52b, whisper_base, yi_6b, qwen1_5_4b, qwen2_1_5b,
-        qwen2_0_5b, qwen2_vl_2b, deepseek_v2_236b, arctic_480b, rwkv6_3b,
-        paper_lenet)
+        qwen2_0_5b, qwen2_vl_2b, deepseek_v2_236b, deepseek_v2_lite,
+        arctic_480b, rwkv6_3b, paper_lenet)
     _LOADED = True

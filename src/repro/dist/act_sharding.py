@@ -2,7 +2,7 @@
 
 The model files never name mesh axes. They call ``constrain(x, kind)``
 at layout-critical points with a *logical* kind ("act", "ffn", "heads4",
-"hd_tp", "moe_tokens", "logits"); an ambient ``act_policy`` context maps
+"hd_tp", "logits"); an ambient ``act_policy`` context maps
 each kind to a PartitionSpec over the active (dp, tp) axes, with
 per-dimension divisibility fallback (an axis that does not divide the
 dimension is dropped rather than poisoning the partitioner). With no
@@ -16,7 +16,6 @@ Kinds (x layout -> pinned dims):
 - ``heads4``     (B, S, H, hd)  batch over dp, heads over tp
 - ``hd_tp``      (B, S, H, hd)  batch over dp, head_dim over tp (decode
                  cache layout: score contraction becomes a partial dot)
-- ``moe_tokens`` (G, T, D)      dispatch groups over dp (group == agent)
 - ``logits``     (B, S, V)      batch over dp, vocab over tp
 """
 from __future__ import annotations
@@ -107,7 +106,7 @@ def _spec_for(kind: str, shape: Tuple[int, ...], pol: _Policy):
         dims[-1] = pol.fit(pol.tp, shape[-1])
     elif kind == "logits" and nd >= 2:
         dims[-1] = pol.fit(pol.tp, shape[-1])
-    # "act" / "moe_tokens": dp on the leading dim only
+    # "act": dp on the leading dim only
     return P(*dims)
 
 

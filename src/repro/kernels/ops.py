@@ -70,6 +70,37 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, kv_lens, *,
                                   interpret=interpret)
 
 
+def _gmm_tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to ``cap`` that divides ``dim``."""
+    for t in range(cap - cap % 128, 127, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    return (256 if m % 256 == 0 else 128, _gmm_tile(k, 512),
+            _gmm_tile(n, 1536))
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def grouped_matmul(x, w, group_sizes, *, impl: str = "auto"):
+    """x (M, K), w (G, K, N), group_sizes (G,) int32: rows
+    [sum(sizes[:g]), sum(sizes[:g+1])) of x times w[g]. Rows past
+    sum(group_sizes) are left undefined (zero off the kernel); neither
+    form spends work on them. Differentiable. On a TPU, "auto" is
+    megablox's grouped matmul (``gmm``, its backward ``gmm`` and
+    ``tgmm``), which visits only the tiles that hold rows;
+    elsewhere ``jax.lax.ragged_dot``."""
+    if impl == "ref" or (impl == "auto" and not _on_tpu()):
+        return jax.lax.ragged_dot(x, w, group_sizes,
+                                  preferred_element_type=x.dtype)
+    from jax.experimental.pallas.ops.tpu.megablox import ops as _mb
+    interpret = impl == "interpret" or not _on_tpu()
+    return _mb.gmm(x, w, group_sizes, x.dtype, _gmm_tiling, None, None,
+                   False, interpret)
+
+
 @functools.partial(jax.jit, static_argnames=("impl",))
 def block_sq_norms(x, *, impl: str = "auto"):
     if impl == "ref" or (impl == "auto" and not _on_tpu()):

@@ -180,7 +180,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
                     state[key])
         bt_specs = batch_specs(rules, batch)
         fresh = jax.ShapeDtypeStruct((n_ag,), jnp.float32)
-        step = T.make_general_step(cfg, tc, mesh, moe_groups=n_ag)
+        step = T.make_general_step(cfg, tc, mesh)
         jf = jax.jit(step,
                      in_shardings=(_mk_shardings(mesh, st_specs),
                                    _mk_shardings(mesh, bt_specs),
@@ -195,7 +195,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         bt_specs = batch_specs(rules, batch)
         # compute-layout specs (manual ZeRO-3 gather targets) for params
         param_cspecs = tree_specs(state["params"], compute_rules)
-        step = T.make_train_step(cfg, tc, moe_groups=n_ag, dp=dp, tp=tp,
+        step = T.make_train_step(cfg, tc, dp=dp, tp=tp,
                                  param_specs=param_cspecs, sizes=sizes)
         jf = jax.jit(step,
                      in_shardings=(_mk_shardings(mesh, st_specs),
@@ -209,7 +209,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         batch = input_specs(cfg, shape, n_ag, "prefill")
         p_specs = tree_specs(params, compute_rules)
         bt_specs = batch_specs(rules, batch)
-        step = V.make_prefill_step(cfg, moe_groups=n_ag, dp=dp, tp=tp, sizes=sizes)
+        step = V.make_prefill_step(cfg, dp=dp, tp=tp, sizes=sizes)
         jf = jax.jit(step, in_shardings=(_mk_shardings(mesh, p_specs),
                                          _mk_shardings(mesh, bt_specs)))
         with jax.set_mesh(mesh):
@@ -223,7 +223,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
                    "cache": cache_specs(rules, batch["cache"],
                                         n_query_heads=cfg.n_heads),
                    "pos": P()}
-        step = V.make_decode_step(cfg, moe_groups=n_ag, dp=dp, tp=tp, sizes=sizes)
+        step = V.make_decode_step(cfg, dp=dp, tp=tp, sizes=sizes)
         jf = jax.jit(step, in_shardings=(_mk_shardings(mesh, p_specs),
                                          _mk_shardings(mesh, b_specs)),
                      donate_argnums=(1,))

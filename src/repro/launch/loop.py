@@ -58,6 +58,10 @@ class LoopHistory:
     grad_norm: List[float] = field(default_factory=list)
     round_time: List[float] = field(default_factory=list)
     sync_round_time: List[float] = field(default_factory=list)
+    # a model with experts: pairs routed to held experts, summed over
+    # layers, and the largest held expert's load over the mean
+    held_pairs: List[float] = field(default_factory=list)
+    held_max_load: List[float] = field(default_factory=list)
 
     @property
     def comm_saving(self) -> float:
@@ -78,7 +82,7 @@ class TrainLoop:
         self.oracle = oracle or StragglerOracle(n_agents, r, seed=seed)
         # the state is donated: old and new params + optimizer moments
         # would not both fit a 16 GB chip at a 0.5B model's full width
-        self.step_fn = jax.jit(make_train_step(cfg, tc, moe_groups=n_agents),
+        self.step_fn = jax.jit(make_train_step(cfg, tc),
                                donate_argnums=(0,))
         self.state = init_state(jax.random.PRNGKey(seed), cfg, tc,
                                 max_pos=max_pos, n_agents=n_agents)
@@ -95,8 +99,9 @@ class TrainLoop:
         """``steps`` iterations of Algorithm 1. Each is a host span on the
         profiler's clock, ``repro.train.step``, holding ``select`` (the
         oracle's first n - r), ``feed`` (the batch, its weights and their
-        uploads), ``dispatch`` (the step call), ``sync`` (the three host
-        reads: loss, grad norm, step) and ``ckpt`` (a save, when due)."""
+        uploads), ``dispatch`` (the step call), ``sync`` (the host reads:
+        the step's metrics in one, then the step counter) and ``ckpt`` (a
+        save, when due)."""
         for i in range(steps):
             with jax.profiler.StepTraceAnnotation(
                     "repro.train.step", step_num=len(self.hist.loss)):
@@ -112,11 +117,15 @@ class TrainLoop:
                 with jax.profiler.TraceAnnotation("repro.train.dispatch"):
                     self.state, metrics = self.step_fn(self.state, batch)
                 with jax.profiler.TraceAnnotation("repro.train.sync"):
-                    loss = float(metrics["loss"])
-                    grad_norm = float(metrics["grad_norm"])
+                    host = jax.device_get(metrics)
                     step = int(self.state["step"])
+                loss = float(host["loss"])
+                grad_norm = float(host["grad_norm"])
                 self.hist.loss.append(loss)
                 self.hist.grad_norm.append(grad_norm)
+                for k in ("held_pairs", "held_max_load"):
+                    if k in host:
+                        getattr(self.hist, k).append(float(host[k]))
                 self.hist.round_time.append(rt)
                 self.hist.sync_round_time.append(full_rt)
                 if (self.ckpt and self.ckpt_every

@@ -28,14 +28,13 @@ def _ctx(dp, tp, sizes=None):
         else contextlib.nullcontext()
 
 
-def make_prefill_step(cfg: ArchConfig, moe_groups: int = 1,
-                      dp=None, tp=None, sizes=None) -> Callable:
+def make_prefill_step(cfg: ArchConfig, dp=None, tp=None,
+                      sizes=None) -> Callable:
     def prefill(params, batch):
         with _ctx(dp, tp, sizes):
             logits, _, cache = apply_model(
                 params, batch["tokens"], cfg, mode="prefill",
-                enc_embed=batch.get("enc_embed"), moe_groups=moe_groups,
-                remat_policy="none")
+                enc_embed=batch.get("enc_embed"), remat_policy="none")
         last = logits[:, -1]
         next_tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
         return next_tok, cache
@@ -43,15 +42,14 @@ def make_prefill_step(cfg: ArchConfig, moe_groups: int = 1,
     return prefill
 
 
-def make_decode_step(cfg: ArchConfig, moe_groups: int = 1,
-                     temperature: float = 0.0, dp=None, tp=None,
-                     sizes=None) -> Callable:
+def make_decode_step(cfg: ArchConfig, temperature: float = 0.0, dp=None,
+                     tp=None, sizes=None) -> Callable:
     def decode(params, batch):
         with _ctx(dp, tp, sizes):
             logits, _, cache = apply_model(
                 params, batch["tokens"], cfg, mode="decode",
                 cache=batch["cache"], cache_index=batch["pos"],
-                moe_groups=moe_groups, remat_policy="none")
+                remat_policy="none")
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return next_tok, cache
 

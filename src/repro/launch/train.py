@@ -36,7 +36,7 @@ from repro.core.ledger import layout_of, ledger_zeros
 from repro.dist import collectives as C
 from repro.dist.registry import resolve_mode
 from repro.dist.sharding import MeshRules, tree_specs, batch_specs
-from repro.launch.mesh import dp_axis_names, n_agents_of
+from repro.launch.mesh import dp_axis_names
 from repro.launch.specs import max_pos_for
 from repro.models.model import apply_model, init_model, lm_loss
 from repro.optim.optimizers import (adamw, sgd, apply_updates,
@@ -54,7 +54,6 @@ class TrainConfig:
     lr_total: int = 1000            # cosine horizon
     warmup: int = 0
     clip_norm: float = 1.0
-    aux_coef: float = 0.01
     remat_policy: str = "full"
     accum_steps: int = 1            # microbatch gradient accumulation
     f: int = 0                      # Byzantine tolerance (cge/trimmed)
@@ -123,8 +122,11 @@ def abstract_state(cfg: ArchConfig, tc: TrainConfig, max_pos: int = 32768,
 # loss
 
 
-def make_loss_fn(cfg: ArchConfig, tc: TrainConfig, moe_groups: int,
-                 dp=None, tp=None, param_specs=None, sizes=None):
+def make_loss_fn(cfg: ArchConfig, tc: TrainConfig, dp=None, tp=None,
+                 param_specs=None, sizes=None):
+    """``loss_fn(params, batch) -> (loss, readings)``: the weighted loss
+    with the model's balance loss, and (a model with experts) the step's
+    ``held_pairs`` and ``held_max_load``."""
     import contextlib
     from repro.dist.act_sharding import act_policy
 
@@ -136,10 +138,11 @@ def make_loss_fn(cfg: ArchConfig, tc: TrainConfig, moe_groups: int,
             logits, aux, _ = apply_model(
                 params, batch["tokens"], cfg, mode="train",
                 enc_embed=batch.get("enc_embed"),
-                moe_groups=moe_groups, remat_policy=tc.remat_policy,
-                param_specs=param_specs)
-            return lm_loss(logits, batch["targets"], batch["weights"], aux,
-                           aux_coef=tc.aux_coef)
+                loss_weights=batch["weights"],
+                remat_policy=tc.remat_policy, param_specs=param_specs)
+            loss = lm_loss(logits, batch["targets"], batch["weights"],
+                           aux.pop("balance"))
+            return loss, aux
     return loss_fn
 
 
@@ -147,14 +150,18 @@ def make_loss_fn(cfg: ArchConfig, tc: TrainConfig, moe_groups: int,
 # masked fast path (pure GSPMD)
 
 
-def make_train_step(cfg: ArchConfig, tc: TrainConfig, moe_groups: int = 1,
-                    dp=None, tp=None, param_specs=None, sizes=None) -> Callable:
+def make_train_step(cfg: ArchConfig, tc: TrainConfig, dp=None, tp=None,
+                    param_specs=None, sizes=None) -> Callable:
     """Algorithm 1 / synchronous step. batch["weights"] carries the agent
-    mask (zeros for dropped stragglers). Pure pjit; FSDP-compatible."""
+    mask (zeros for dropped stragglers). Pure pjit; FSDP-compatible.
+    Returns (state, metrics): loss, grad norm and the loss function's
+    readings (``make_loss_fn``; the last microbatch's with
+    accumulation)."""
     resolve_mode(tc.mode)               # fail fast on unknown modes
     opt = make_optimizer(tc)
-    loss_fn = make_loss_fn(cfg, tc, moe_groups, dp=dp, tp=tp,
+    loss_fn = make_loss_fn(cfg, tc, dp=dp, tp=tp,
                            param_specs=param_specs, sizes=sizes)
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def step(state, batch):
         if tc.accum_steps > 1:
@@ -169,23 +176,23 @@ def make_train_step(cfg: ArchConfig, tc: TrainConfig, moe_groups: int = 1,
                     lambda x: jax.lax.dynamic_slice_in_dim(
                         x, i * (x.shape[0] // k), x.shape[0] // k, axis=0)
                     if x.ndim else x, batch)
-                l, g = jax.value_and_grad(loss_fn)(state["params"], mb)
+                (l, readings), g = grad_fn(state["params"], mb)
                 acc = jax.tree.map(
                     lambda a, gg: a + gg.astype(jnp.float32), acc, g)
-                return (acc, lsum + l), None
+                return (acc, lsum + l), readings
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state["params"])
-            (gsum, lsum), _ = jax.lax.scan(
+            (gsum, lsum), readings = jax.lax.scan(
                 micro, (zeros, jnp.zeros((), jnp.float32)),
                 jnp.arange(k))
+            readings = jax.tree.map(lambda r: r[-1], readings)
             grads = jax.tree.map(
                 lambda g, p: (g / k).astype(p.dtype), gsum,
                 state["params"])
             loss = lsum / k
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(state["params"],
-                                                      batch)
+            (loss, readings), grads = grad_fn(state["params"], batch)
         if tc.clip_norm:
             grads, gnorm = clip_by_global_norm(grads, tc.clip_norm)
         else:
@@ -199,7 +206,7 @@ def make_train_step(cfg: ArchConfig, tc: TrainConfig, moe_groups: int = 1,
         for k in ("ledger", "err"):
             if k in state:
                 new_state[k] = state[k]
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_state, {"loss": loss, "grad_norm": gnorm, **readings}
 
     return step
 
@@ -208,26 +215,25 @@ def make_train_step(cfg: ArchConfig, tc: TrainConfig, moe_groups: int = 1,
 # general path (partial-manual shard_map over DP axes)
 
 
-def make_general_step(cfg: ArchConfig, tc: TrainConfig, mesh,
-                      moe_groups: int = 1) -> Callable:
+def make_general_step(cfg: ArchConfig, tc: TrainConfig, mesh) -> Callable:
     """Per-agent gradient paths: cge / stale / trimmed / quantized.
 
     Signature: step(state, batch, fresh_mask (n_agents,) f32) -> (state, m).
     """
     opt = make_optimizer(tc)
     dp = dp_axis_names(mesh)
-    n = n_agents_of(mesh)
     rule = resolve_mode(tc.mode)        # single dispatch point (registry)
     # NOTE: activation pins inside the partial-manual region trigger an
     # XLA partitioner check-failure at 256+ devices (both Shardy and legacy
     # GSPMD); the general path therefore runs without them and relies on
     # propagation from the TP-sharded params (see EXPERIMENTS.md §Perf).
-    loss_fn = make_loss_fn(cfg, tc, max(moe_groups // n, 1))
+    loss_fn = make_loss_fn(cfg, tc)
 
     def local(state, batch, fresh_mask):
         me = C.agent_index(dp)
         mask_self = fresh_mask[me]
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
+        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state["params"], batch)
 
         if tc.mode == "cge":
             agg, keep = rule.collective(grads, mask_self > 0, tc.f, dp)

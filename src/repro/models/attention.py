@@ -8,12 +8,14 @@ the plain form.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, YarnConfig
 from repro.dist.act_sharding import constrain, current_policy
 from repro.dist.sharding import current_serve_tp
 from repro.models.layers import apply_rope, dense_init, _dtype
@@ -29,12 +31,13 @@ NEG_INF = -1e30
 
 
 def plain_attention(q, k, v, *, causal: bool, q_offset=0,
-                    kv_len: Optional[jnp.ndarray] = None):
+                    kv_len: Optional[jnp.ndarray] = None,
+                    scale: Optional[float] = None):
     """q:(B,S,H,D) k,v:(B,T,H,D) (KV already repeated to H heads).
-    Returns (B,S,H,D)."""
+    ``scale`` defaults to D^-0.5. Returns (B,S,H,D)."""
     b, s, h, d = q.shape
     t = k.shape[1]
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     s_ = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
     if causal:
         qpos = jnp.arange(s) + q_offset
@@ -48,7 +51,8 @@ def plain_attention(q, k, v, *, causal: bool, q_offset=0,
     return jnp.einsum("bhst,bthd->bshd", w.astype(q.dtype), v)
 
 
-def chunked_attention(q, k, v, *, causal: bool, chunk: int = CHUNK):
+def chunked_attention(q, k, v, *, causal: bool, chunk: int = CHUNK,
+                      scale: Optional[float] = None):
     """Online-softmax over key chunks. q,k:(B,S,H,D) v:(B,T,H,Dv)
     (Dv may differ from D, e.g. MLA's v_head_dim)."""
     b, s, h, d = q.shape
@@ -57,7 +61,7 @@ def chunked_attention(q, k, v, *, causal: bool, chunk: int = CHUNK):
     c = min(chunk, t)
     assert t % c == 0, (t, c)
     n = t // c
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     qpos = jnp.arange(s)
 
     def body(carry, i):
@@ -107,10 +111,12 @@ def flash_gate(q, k, v, *, causal: bool, kv_len=None) -> bool:
             and current_serve_tp() is None and ops._on_tpu())
 
 
-def attention_math(q, k, v, *, causal: bool, kv_len=None):
+def attention_math(q, k, v, *, causal: bool, kv_len=None,
+                   scale: Optional[float] = None):
     if q.shape[1] == k.shape[1] and q.shape[1] > PLAIN_MAX_SEQ:
-        return chunked_attention(q, k, v, causal=causal)
-    return plain_attention(q, k, v, causal=causal, kv_len=kv_len)
+        return chunked_attention(q, k, v, causal=causal, scale=scale)
+    return plain_attention(q, k, v, causal=causal, kv_len=kv_len,
+                           scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -306,10 +312,14 @@ def init_mla(rng, cfg: ArchConfig):
     d, dt, h = cfg.d_model, _dtype(cfg), cfg.n_heads
     ks = jax.random.split(rng, 8)
     qd = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        q = {"w_q": dense_init(ks[0], d, h * qd, dt)}
+    else:
+        q = {"w_dq": dense_init(ks[0], d, m.q_lora_rank, dt),
+             "q_norm": jnp.ones((m.q_lora_rank,), dt),
+             "w_uq": dense_init(ks[1], m.q_lora_rank, h * qd, dt)}
     return {
-        "w_dq": dense_init(ks[0], d, m.q_lora_rank, dt),
-        "q_norm": jnp.ones((m.q_lora_rank,), dt),
-        "w_uq": dense_init(ks[1], m.q_lora_rank, h * qd, dt),
+        **q,
         "w_dkv": dense_init(ks[2], d, m.kv_lora_rank, dt),
         "kv_norm": jnp.ones((m.kv_lora_rank,), dt),
         "w_kr": dense_init(ks[3], d, m.qk_rope_head_dim, dt),
@@ -319,6 +329,45 @@ def init_mla(rng, cfg: ArchConfig):
                            ).reshape(m.kv_lora_rank, h, m.v_head_dim),
         "wo": dense_init(ks[6], h * m.v_head_dim, d, dt),
     }
+
+
+# ---------------------------------------------------------------------------
+# YaRN (DeepSeek-V2's rope_scaling; its modeling code's yarn_* helpers)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YarnConfig) -> np.ndarray:
+    """(dim/2,) rotary frequencies: the original ones for the fast
+    dimensions, divided by ``factor`` for the slow ones, and a linear ramp
+    between, over ``original_max_position_embeddings``."""
+    def corr(rot):                                  # yarn_find_correction_dim
+        return dim * math.log(y.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(corr(y.beta_fast)), 0)
+    high = min(math.ceil(corr(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    return (extra / y.factor * ramp + extra * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def _mla_rope(cfg: ArchConfig):
+    """(inv_freq or None, factor on the rotated dims, softmax scale)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = m.yarn
+    if y is None:
+        return None, 1.0, scale
+    mall = yarn_mscale(y.factor, y.mscale_all_dim)
+    return (jnp.asarray(yarn_inv_freq(m.qk_rope_head_dim, cfg.rope_theta,
+                                      y)),
+            yarn_mscale(y.factor, y.mscale) / mall, scale * mall * mall)
 
 
 def _rms(x, scale):
@@ -359,20 +408,37 @@ def _mla_tp_shard(absorbed, q_nope, q_rope, w_uk, w_uv, ckv, kr, kv_len,
 
 def apply_mla(p, x, cfg: ArchConfig, *, positions, cache=None,
               cache_index=None, return_cache=False, page_table=None):
+    """Latent attention under the name scope ``repro.attn.mla``
+    (projections and attention; backward ops carry it too)."""
+    with jax.named_scope("repro.attn.mla"):
+        return _apply_mla(p, x, cfg, positions=positions, cache=cache,
+                          cache_index=cache_index, return_cache=return_cache,
+                          page_table=page_table)
+
+
+def _apply_mla(p, x, cfg: ArchConfig, *, positions, cache, cache_index,
+               return_cache, page_table):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
     nope, rp, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+    inv_freq, rope_mul, scale = _mla_rope(cfg)
 
-    cq = _rms(jnp.einsum("bsd,dl->bsl", x, p["w_dq"]), p["q_norm"])
-    q = jnp.einsum("bsl,le->bse", cq, p["w_uq"]).reshape(b, s, h, nope + rp)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    def rope(t):
+        t = apply_rope(t, positions, cfg.rope_theta, inv_freq=inv_freq)
+        return t if rope_mul == 1.0 else t * jnp.asarray(rope_mul, t.dtype)
+
+    if m.q_lora_rank is None:
+        q = jnp.einsum("bsd,de->bse", x, p["w_q"])
+    else:
+        cq = _rms(jnp.einsum("bsd,dl->bsl", x, p["w_dq"]), p["q_norm"])
+        q = jnp.einsum("bsl,le->bse", cq, p["w_uq"])
+    q = q.reshape(b, s, h, nope + rp)
+    q_nope, q_rope = q[..., :nope], rope(q[..., nope:])
 
     ckv_new = _rms(jnp.einsum("bsd,dl->bsl", x, p["w_dkv"]), p["kv_norm"])
-    kr_new = apply_rope(
-        jnp.einsum("bsd,dr->bsr", x, p["w_kr"])[:, :, None, :],
-        positions, cfg.rope_theta)[:, :, 0, :]
+    kr_new = rope(jnp.einsum("bsd,dr->bsr", x, p["w_kr"])[:, :, None, :]
+                  )[:, :, 0, :]
 
     if cache is not None and cache_index is not None:
         # absorbed decode: score in latent space, never materialize K/V.
@@ -406,7 +472,6 @@ def apply_mla(p, x, cfg: ArchConfig, *, positions, cache=None,
             kv_len = jnp.broadcast_to(idx + 1, (b,))
             new_cache = {"ckv": ckv, "kr": kr}
         t = ckv.shape[1]
-        scale = (nope + rp) ** -0.5
         cdt = x.dtype
 
         def _absorbed(qn, qr, wuk, wuv, ckv_, kr_, kl):
@@ -439,7 +504,7 @@ def apply_mla(p, x, cfg: ArchConfig, *, positions, cache=None,
                            "heads4")
         k = constrain(k, "heads4")
         v = constrain(v, "heads4")
-        out = attention_math(q_full, k, v, causal=True)
+        out = attention_math(q_full, k, v, causal=True, scale=scale)
         new_cache = {"ckv": ckv_new, "kr": kr_new} if return_cache else None
 
     y = jnp.einsum("bse,ed->bsd",

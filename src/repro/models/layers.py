@@ -74,10 +74,12 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               mrope_sections: Optional[Tuple[int, ...]] = None) -> jnp.ndarray:
-    """x: (B,S,H,D). positions: (B,S) int, or (3,B,S) for M-RoPE."""
+               mrope_sections: Optional[Tuple[int, ...]] = None,
+               inv_freq: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """x: (B,S,H,D). positions: (B,S) int, or (3,B,S) for M-RoPE.
+    ``inv_freq`` (D/2,) replaces theta's frequencies (YaRN)."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                       # (d/2,)
+    inv = rope_freqs(d, theta) if inv_freq is None else inv_freq  # (d/2,)
     if positions.ndim == 3:                          # M-RoPE
         assert mrope_sections is not None
         sec_ids = jnp.repeat(

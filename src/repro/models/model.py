@@ -5,6 +5,11 @@ archs: period 1; Jamba: period 8). Params and KV/SSM caches carry a leading
 ``n_periods`` axis so the whole stack is a single ``lax.scan`` — compact HLO,
 fast 512-device dry-run compiles. The period body is rematerialized
 (``jax.checkpoint``) under a configurable policy.
+
+``first_k_dense`` leading attention layers with a dense MLP (DeepSeek's
+``first_k_dense_replace``) run first, as a scan of their own under the
+same remat policy: params ``lead``, and in a decode cache one more entry
+after the pattern positions'.
 """
 from __future__ import annotations
 
@@ -28,7 +33,7 @@ PyTree = Any
 # init
 
 
-def _init_layer(rng, cfg: ArchConfig, kind: str, layer_idx: int,
+def _init_layer(rng, cfg: ArchConfig, kind: str, moe: bool,
                 cross: bool = False):
     ks = jax.random.split(rng, 4)
     p: Dict[str, PyTree] = {"norm1": L.init_norm(cfg),
@@ -45,7 +50,7 @@ def _init_layer(rng, cfg: ArchConfig, kind: str, layer_idx: int,
 
     if kind == "rwkv":
         p["ffn"] = S.init_rwkv_channel(ks[1], cfg)
-    elif cfg.moe_on_layer(layer_idx):
+    elif moe:
         p["ffn"] = M.init_moe(ks[1], cfg, cfg.moe)
     else:
         p["ffn"] = L.init_mlp(ks[1], cfg)
@@ -69,6 +74,10 @@ def init_model(rng, cfg: ArchConfig, max_pos: int = 32768):
             ks[5], (max_pos, cfg.d_model), jnp.float32) * 0.01
         ).astype(jnp.dtype(cfg.param_dtype))
 
+    if cfg.first_k_dense:
+        params["lead"] = _stack_layers([
+            _init_layer(jax.random.fold_in(ks[6], li), cfg, "attn", False)
+            for li in range(cfg.first_k_dense)])
     period = cfg.period
     blocks = []
     for pos in range(period):
@@ -77,7 +86,7 @@ def init_model(rng, cfg: ArchConfig, max_pos: int = 32768):
             idx = pi * period + pos
             per_period.append(_init_layer(
                 jax.random.fold_in(ks[1], idx), cfg, cfg.layer_pattern[pos],
-                idx, cross=cfg.encoder_decoder))
+                cfg.moe_on_layer(idx), cross=cfg.encoder_decoder))
         blocks.append(_stack_layers(per_period))
     params["blocks"] = tuple(blocks)
     params["norm_f"] = L.init_norm(cfg)
@@ -86,7 +95,7 @@ def init_model(rng, cfg: ArchConfig, max_pos: int = 32768):
         enc = []
         for li in range(cfg.encoder_layers):
             enc.append(_init_layer(jax.random.fold_in(ks[2], li), cfg,
-                                   "attn", li))
+                                   "attn", False))
         params["encoder"] = _stack_layers(enc)
         params["enc_norm_f"] = L.init_norm(cfg)
         params["enc_pos"] = (jax.random.normal(
@@ -101,7 +110,9 @@ def init_model(rng, cfg: ArchConfig, max_pos: int = 32768):
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int,
                abstract: bool = False):
-    """Decode cache pytree; leading n_periods axis per pattern position."""
+    """Decode cache pytree; leading n_periods axis per pattern position,
+    then (``first_k_dense``) the leading layers' with a leading axis of
+    their count."""
     dt = jnp.dtype(cfg.compute_dtype)
     P = cfg.n_periods
     hd = cfg.resolved_head_dim
@@ -111,16 +122,18 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
             return jax.ShapeDtypeStruct(shape, d)
         return jnp.zeros(shape, d)
 
+    def attn(n):
+        if cfg.attention == "mla":
+            m = cfg.mla
+            return {"ckv": z((n, batch, max_len, m.kv_lora_rank)),
+                    "kr": z((n, batch, max_len, m.qk_rope_head_dim))}
+        return {"k": z((n, batch, max_len, cfg.n_kv_heads, hd)),
+                "v": z((n, batch, max_len, cfg.n_kv_heads, hd))}
+
     blocks = []
     for pos, kind in enumerate(cfg.layer_pattern):
         if kind == "attn":
-            if cfg.attention == "mla":
-                m = cfg.mla
-                mix = {"ckv": z((P, batch, max_len, m.kv_lora_rank)),
-                       "kr": z((P, batch, max_len, m.qk_rope_head_dim))}
-            else:
-                mix = {"k": z((P, batch, max_len, cfg.n_kv_heads, hd)),
-                       "v": z((P, batch, max_len, cfg.n_kv_heads, hd))}
+            mix = attn(P)
             ffn = {}
         elif kind == "mamba":
             di = cfg.ssm.expand * cfg.d_model
@@ -142,6 +155,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
                             "cv": z((P, batch, cfg.encoder_seq,
                                      cfg.n_kv_heads, hd))}
         blocks.append(blk)
+    if cfg.first_k_dense:
+        blocks.append({"mixer": attn(cfg.first_k_dense), "ffn": {}})
     return tuple(blocks)
 
 
@@ -170,10 +185,11 @@ def _apply_mixer(p, x, kind, cfg, *, positions, cache, cache_index,
     raise ValueError(kind)
 
 
-def _apply_layer(p, x, kind, cfg, *, layer_idx, positions, moe_groups,
+def _apply_layer(p, x, kind, cfg, *, moe, positions, loss_weights=None,
                  cache=None, cache_index=None, return_cache=False,
                  enc_out=None, page_table=None):
-    """Returns (x, aux, new_cache)."""
+    """Returns (x, aux, new_cache); aux holds an MoE layer's readings
+    (``repro.models.moe.apply_moe``), else nothing."""
     mix_cache = cache["mixer"] if cache else None
     h = L.apply_norm(p["norm1"], x, cfg)
     y, new_mix = _apply_mixer(p["mixer"], h, kind, cfg, positions=positions,
@@ -196,7 +212,7 @@ def _apply_layer(p, x, kind, cfg, *, layer_idx, positions, moe_groups,
                                        return_cache=return_cache)
         x = constrain(x + y, "act")
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = {}
     h = L.apply_norm(p["norm2"], x, cfg)
     new_ffn = {}
     if kind == "rwkv":
@@ -205,8 +221,8 @@ def _apply_layer(p, x, kind, cfg, *, layer_idx, positions, moe_groups,
                                             cache=ffn_cache,
                                             return_cache=return_cache)
         new_ffn = new_ffn_c or {}
-    elif cfg.moe_on_layer(layer_idx):
-        y, aux = M.apply_moe(p["ffn"], h, cfg, cfg.moe, n_groups=moe_groups)
+    elif moe:
+        y, aux = M.apply_moe(p["ffn"], h, cfg, cfg.moe, loss_weights)
     else:
         y = L.apply_mlp(p["ffn"], h, cfg)
     x = constrain(x + y, "act")
@@ -246,6 +262,15 @@ def _apply_encoder(params, enc_embed, cfg: ArchConfig):
 # forward
 
 
+def _add_aux(acc, a):
+    """Fold one layer's readings into the running ones."""
+    out = dict(acc)
+    for k, v in a.items():
+        out[k] = jnp.maximum(acc[k], v) if k == "held_max_load" \
+            else acc[k] + v
+    return out
+
+
 def _positions_for(cfg: ArchConfig, b: int, s: int, offset=0):
     off = jnp.asarray(offset, jnp.int32)
     if off.ndim:                                     # per-sequence offsets
@@ -260,11 +285,15 @@ def _positions_for(cfg: ArchConfig, b: int, s: int, offset=0):
 
 def apply_model(params, tokens, cfg: ArchConfig, *,
                 enc_embed=None, cache=None, cache_index=None,
-                mode: str = "train", moe_groups: int = 1,
+                mode: str = "train", loss_weights=None,
                 remat_policy: str = "full",
                 logits_chunk: int = 0,
                 param_specs=None, page_table=None):
-    """Returns (logits, aux_loss, new_cache).
+    """Returns (logits, aux, new_cache). ``aux``: ``balance``, the MoE
+    balance loss summed over layers (0 without experts), and for a model
+    with experts the step's ``held_pairs`` (summed over layers) and
+    ``held_max_load`` (the largest over layers). ``loss_weights`` (B, S)
+    weight the rows of the balance loss (``repro.models.moe``).
 
     mode: "train" (no cache), "prefill" (returns populated cache),
           "decode" (tokens (B,1), cache + cache_index required;
@@ -294,8 +323,10 @@ def apply_model(params, tokens, cfg: ArchConfig, *,
             if key in params and key in param_specs:
                 params[key] = constrain_tree(params[key], param_specs[key])
         blk_specs = [strip_leading(ps) for ps in param_specs["blocks"]]
+        lead_specs = ([strip_leading(param_specs["lead"])]
+                      if "lead" in param_specs else None)
     else:
-        blk_specs = None
+        blk_specs = lead_specs = None
 
     x = constrain(L.embed_tokens(params["embed"], tokens, cfg), "act")
     if cfg.rope == "learned":
@@ -315,44 +346,62 @@ def apply_model(params, tokens, cfg: ArchConfig, *,
         # decode mode: cross K/V comes from cache
 
     pattern = cfg.layer_pattern
+
+    def stack_body(kinds, moes, specs):
+        """The scan body over one stack: a period of ``kinds`` layers."""
+        def body(carry, xs):
+            x, aux = carry
+            x = constrain(x, "act")
+            if cache is not None:
+                blk_params, blk_caches = xs
+            else:
+                blk_params, blk_caches = xs, [None] * len(kinds)
+            if specs is not None:
+                blk_params = tuple(
+                    constrain_tree(bp, bs)
+                    for bp, bs in zip(blk_params, specs))
+            new_caches = []
+            for pos, kind in enumerate(kinds):
+                x, a, nc = _apply_layer(
+                    blk_params[pos], x, kind, cfg, moe=moes[pos],
+                    positions=positions, loss_weights=loss_weights,
+                    cache=blk_caches[pos] if cache is not None else None,
+                    cache_index=cache_index if decode else None,
+                    return_cache=prefill, enc_out=enc_out,
+                    page_table=page_table if decode else None)
+                aux = _add_aux(aux, a)
+                new_caches.append(nc)
+            out_caches = tuple(new_caches) if (decode or prefill) else None
+            return (x, aux), out_caches
+
+        if mode == "train" and remat_policy != "none":
+            policy = None
+            if remat_policy == "dots":
+                policy = \
+                    jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+            return jax.checkpoint(body, policy=policy, prevent_cse=False)
+        return body
+
+    aux = {"balance": jnp.zeros((), jnp.float32)}
+    if cfg.moe is not None:
+        aux.update(held_pairs=jnp.zeros((), jnp.float32),
+                   held_max_load=jnp.zeros((), jnp.float32))
+    lead_cache = None
+    if cfg.first_k_dense:
+        lead = (params["lead"],)
+        xs = (lead, (cache[len(pattern)],)) if cache is not None else lead
+        (x, aux), lead_cache = jax.lax.scan(
+            stack_body(("attn",), (False,), lead_specs), (x, aux), xs)
+        cache = cache[:len(pattern)] if cache is not None else None
+
     blocks = params["blocks"]
-
-    def period_body(carry, xs):
-        x, aux = carry
-        x = constrain(x, "act")
-        if cache is not None:
-            blk_params, blk_caches = xs
-        else:
-            blk_params, blk_caches = xs, [None] * len(pattern)
-        if blk_specs is not None:
-            blk_params = tuple(
-                constrain_tree(bp, bs)
-                for bp, bs in zip(blk_params, blk_specs))
-        new_caches = []
-        for pos, kind in enumerate(pattern):
-            x, a, nc = _apply_layer(
-                blk_params[pos], x, kind, cfg, layer_idx=pos,
-                positions=positions, moe_groups=moe_groups,
-                cache=blk_caches[pos] if cache is not None else None,
-                cache_index=cache_index if decode else None,
-                return_cache=prefill, enc_out=enc_out,
-                page_table=page_table if decode else None)
-            aux = aux + a
-            new_caches.append(nc)
-        out_caches = tuple(new_caches) if (decode or prefill) else None
-        return (x, aux), out_caches
-
-    body = period_body
-    if mode == "train" and remat_policy != "none":
-        policy = None
-        if remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        body = jax.checkpoint(period_body, policy=policy,
-                              prevent_cse=False)
-
+    body = stack_body(pattern, tuple(cfg.moe_on_layer(pos)
+                                     for pos in range(len(pattern))),
+                      blk_specs)
     xs = (blocks, cache) if cache is not None else blocks
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       xs)
+    (x, aux), new_cache = jax.lax.scan(body, (x, aux), xs)
+    if lead_cache is not None:
+        new_cache = new_cache + lead_cache
     x = constrain(L.apply_norm(params["norm_f"], x, cfg), "act")
     if logits_chunk and not decode:
         logits = None  # computed chunked inside the loss (see lm_loss_chunked)
@@ -365,9 +414,11 @@ def apply_model(params, tokens, cfg: ArchConfig, *,
 # losses
 
 
-def lm_loss(logits, targets, weights, aux=0.0, aux_coef: float = 0.01):
-    """Weighted token cross-entropy. weights carries padding *and* the
-    paper's Algorithm-1 agent mask (masked agents' tokens get weight 0).
+def lm_loss(logits, targets, weights, aux=0.0):
+    """Weighted token cross-entropy plus ``aux`` (the model's balance
+    loss, already scaled by its coefficient). weights carries padding
+    *and* the paper's Algorithm-1 agent mask (masked agents' tokens get
+    weight 0).
 
     Sharding-friendly: the gold logit is a fused one-hot contraction (an
     iota-compare-select fused into the vocab reduction) instead of
@@ -384,7 +435,7 @@ def lm_loss(logits, targets, weights, aux=0.0, aux_coef: float = 0.01):
     xent = logz - gold
     w = weights.astype(jnp.float32)
     loss = jnp.sum(xent * w) / jnp.maximum(jnp.sum(w), 1.0)
-    return loss + aux_coef * aux
+    return loss + aux
 
 
 def classifier_loss(logits, labels, weights):
@@ -412,8 +463,9 @@ def count_params(cfg: ArchConfig, active_only: bool = False,
     total = sum(_math.prod(l.shape) for l in jax.tree.leaves(shapes))
     if active_only and cfg.moe is not None:
         moe = cfg.moe
-        n_moe = sum(1 for i in range(cfg.n_layers) if cfg.moe_on_layer(i))
+        n_moe = sum(1 for i in range(cfg.n_layers - cfg.first_k_dense)
+                    if cfg.moe_on_layer(i))
         per_expert = 3 * cfg.d_model * moe.d_ff_expert
-        inactive = n_moe * (moe.num_experts - moe.top_k) * per_expert
+        inactive = n_moe * max(moe.n_held - moe.top_k, 0) * per_expert
         total -= inactive
     return total

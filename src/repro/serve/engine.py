@@ -30,16 +30,13 @@ as span stats): ``submit``; ``step``, holding ``admit`` (which holds
 for the preemption choice, ``decode`` and ``retire``. With the profiler
 off a span costs about a microsecond; nothing else here keeps time.
 
-MoE runs *drop-free* at inference (capacity_factor raised to
-num_experts / top_k, so capacity >= tokens-per-group always): capacity
-binning is a training-throughput trade-off, and at serving time dropping
-would make a request's tokens depend on whatever else shares its decode
-batch — continuous batching must be batch-composition-invariant.
+MoE is drop-free (``repro.models.moe``), so a request's tokens never
+depend on whatever else shares its decode batch — continuous batching
+must be batch-composition-invariant.
 """
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 from typing import Dict, List, Optional
 
 import jax
@@ -119,13 +116,6 @@ class ServeEngine:
         self.cfg = cfg
         self.superstep_k = int(superstep_k)
         self.prefix_cache = prefix_cache
-        if cfg.moe is not None:
-            cfg = dataclasses.replace(
-                cfg, moe=dataclasses.replace(
-                    cfg.moe,
-                    capacity_factor=float(cfg.moe.num_experts)
-                    / cfg.moe.top_k))
-        self.infer_cfg = cfg
         self.ccfg = ccfg or PagedCacheConfig()
         self.kv = PagedKVCache(cfg, self.ccfg,
                                enable_prefix=(prefix_cache == "on"),
@@ -229,7 +219,7 @@ class ServeEngine:
         # prefixes, but a recurrent (SSM/RWKV) state would absorb the pad
         # garbage — those archs bucket by exact length instead.
         self._pad_buckets = all(k == "attn"
-                                for k in self.infer_cfg.layer_pattern)
+                                for k in self.cfg.layer_pattern)
 
     # ------------------------------------------------------------------
     def submit(self, prompt, max_new_tokens: int, priority: int = 0,
@@ -512,13 +502,13 @@ class ServeEngine:
         the restart (max of live and image — a rejoined replica must
         never reuse a rid the fleet already tracked), and a prefix
         cache restarts cold (its hash index is not part of the image)."""
-        self.kv = PagedKVCache(self.infer_cfg, self.ccfg,
+        self.kv = PagedKVCache(self.cfg, self.ccfg,
                                enable_prefix=(self.prefix_cache == "on"),
                                mesh=self.mesh, rules=self.rules)
         self.sched = Scheduler(self.ccfg, policy=self.sched.policy)
         if image is not None:
             blocks = list(self.kv.cache)
-            for pos, kind in enumerate(self.infer_cfg.layer_pattern):
+            for pos, kind in enumerate(self.cfg.layer_pattern):
                 blk = dict(blocks[pos])
                 for part in ("mixer", "ffn"):
                     loaded = {}

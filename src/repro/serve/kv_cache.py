@@ -200,6 +200,9 @@ class PagedKVCache:
         if cfg.encoder_decoder:
             raise NotImplementedError(
                 "paged serving supports decoder-only archs")
+        if cfg.first_k_dense:
+            raise NotImplementedError(
+                "paged serving of leading dense layers (first_k_dense)")
         self.cfg = cfg
         self.ccfg = ccfg
         self.alloc = PageAllocator(ccfg.num_pages)

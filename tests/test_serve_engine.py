@@ -51,7 +51,7 @@ def test_engine_ragged_matches_rollout(arch):
     out = eng.run()
     assert eng.sched.peak_active <= 2 and eng.stats["admitted"] == 3
     for p, n, rid in zip(prompts, budgets, rids):
-        ref = _rollout(params, eng.infer_cfg, jnp.asarray(p), n)[0, p.size:]
+        ref = _rollout(params, eng.cfg, jnp.asarray(p), n)[0, p.size:]
         np.testing.assert_array_equal(out[rid], ref)
     # all pages returned after the last retire
     assert eng.kv.alloc.n_used == 0
@@ -82,7 +82,7 @@ def test_engine_midstream_admission_slot_reuse():
     r2 = eng.submit(p2, 4)                   # arrives mid-stream
     out = eng.run()
     for p, n, rid in ((p1, 3, r1), (p2, 4, r2)):
-        ref = _rollout(params, eng.infer_cfg, jnp.asarray(p), n)[0, p.size:]
+        ref = _rollout(params, eng.cfg, jnp.asarray(p), n)[0, p.size:]
         np.testing.assert_array_equal(out[rid], ref)
     assert eng.sched.finished[r2].slot == eng.sched.finished[r1].slot
 
@@ -204,7 +204,7 @@ def test_prefill_shapes_bucket_by_page():
     out = eng.run()
     assert eng.stats["prefill_calls"] == 1          # one shared bucket
     for p, rid in zip(prompts, rids):
-        ref = _rollout(params, eng.infer_cfg, jnp.asarray(p), 4)[0, p.size:]
+        ref = _rollout(params, eng.cfg, jnp.asarray(p), 4)[0, p.size:]
         np.testing.assert_array_equal(out[rid], ref)
     # recurrent state would absorb right-padding: rwkv buckets exactly
     cfg2, params2 = _setup("rwkv6-3b")
@@ -213,12 +213,20 @@ def test_prefill_shapes_bucket_by_page():
 
 
 def test_moe_serving_is_drop_free():
-    """Serving raises the MoE capacity factor so capacity >= tokens per
-    group — a request's tokens must not depend on its batch-mates."""
-    cfg, _ = _setup("deepseek-v2-236b")
-    eng = ServeEngine(init_model(jax.random.PRNGKey(0), cfg, max_pos=32),
-                      cfg, PagedCacheConfig(num_slots=1, page_size=4,
-                                            num_pages=4,
-                                            max_pages_per_seq=2))
-    moe = eng.infer_cfg.moe
-    assert moe.capacity_factor * moe.top_k >= moe.num_experts
+    """The MoE layer drops no (token, expert) pair, so a request's tokens
+    do not depend on its batch-mates: served beside two others in one
+    batch, it decodes exactly as it does alone."""
+    cfg, params = _setup("deepseek-v2-236b")
+    ccfg = PagedCacheConfig(num_slots=3, page_size=4, num_pages=24,
+                            max_pages_per_seq=4)
+    rng = np.random.default_rng(7)
+    prompts = [np.asarray(rng.integers(0, cfg.vocab_size, s), np.int32)
+               for s in (6, 5, 7)]
+    alone = ServeEngine(params, cfg, ccfg)
+    rid = alone.submit(prompts[0], 5)
+    want = alone.run()[rid]
+    eng = ServeEngine(params, cfg, ccfg)
+    rids = [eng.submit(p, 5) for p in prompts]
+    out = eng.run()
+    assert eng.sched.peak_active == 3
+    np.testing.assert_array_equal(out[rids[0]], want)

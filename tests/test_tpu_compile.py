@@ -105,3 +105,23 @@ def test_causal_gqa_flash_compiles(one_chip, b, s, h, hkv, d, grad):
                         _sds((b, s, hkv, d), bf, one_chip),
                         _sds((b, s, hkv, d), bf, one_chip))
     assert compiled.as_text().count("tpu_custom_call") >= (2 if grad else 1)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles(one_chip, monkeypatch, k, n):
+    """The held experts' grouped matmul (megablox ``gmm``), forward and
+    backward (``gmm`` and ``tgmm``), at DeepSeek-V2-Lite's expert widths
+    and one chunk's pair buffer (4,096 tokens, top-6) over 8 experts."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)   # the chip's path
+    bf = jnp.bfloat16
+
+    def fn(x, w, sizes):
+        return jax.grad(lambda x, w: jnp.sum(jnp.square(ops.grouped_matmul(
+            x, w, sizes).astype(jnp.float32))), argnums=(0, 1))(x, w)
+
+    compiled = _compile(fn, _sds((4096 * 6, k), bf, one_chip),
+                        _sds((8, k, n), bf, one_chip),
+                        _sds((8,), jnp.int32, one_chip))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
